@@ -368,7 +368,7 @@ def main(argv=None) -> int:
                          "and 0 retraces (repeatable)")
     ap.add_argument("--tune-cache-dir", metavar="PATH", default=None,
                     help="tune-cache root for --check-tune (default "
-                         "$REPRO_TUNE_CACHE_DIR or ~/.cache/repro-tune); "
+                         "$REPRO_TUNE_CACHE_DIR or <checkout>/.cache/tune); "
                          "CI persists this dir across runs")
     ap.add_argument("--metrics-snapshot", metavar="PATH", default=None,
                     help="dump the process-wide obs metrics registry "

@@ -158,18 +158,17 @@ class CompiledPlan:
                 "disabled, running single-device", self.graph.name,
                 const_outputs)
             return
-        from jax.experimental.shard_map import shard_map
         self._batch_spec = P(axes if len(axes) > 1 else axes[0])
         # shard_map (not GSPMD auto-partitioning): each device traces the
         # plan body on its *local* batch shard with concrete local shapes,
         # so the Pallas kernel calls inside segments stay single-device
         # programs — no reliance on the SPMD partitioner understanding a
         # custom call.  Data-parallel with replicated weights needs no
-        # cross-device collectives in the body (check_rep is off because
+        # cross-device collectives in the body (check_vma is off because
         # the body closes over per-segment kernel partials).
-        spmd = shard_map(plan, mesh=self.mesh,
-                         in_specs=(P(), self._batch_spec),
-                         out_specs=self._batch_spec, check_rep=False)
+        spmd = jax.shard_map(plan, mesh=self.mesh,
+                             in_specs=(P(), self._batch_spec),
+                             out_specs=self._batch_spec, check_vma=False)
         self._jitted_spmd = jax.jit(spmd)
 
     @property
@@ -453,11 +452,9 @@ def compile_graph(graph: QonnxGraph, *, run_cleanup: bool = True,
                    "off" keeps the module-default blocks; "cached" answers
                    from the on-disk tune cache (defaults on miss, never
                    times anything); "search" additionally measures unseen
-                   workloads and persists the winners.  Modes other than
-                   "off" also enable the JAX persistent compilation cache
-                   so jitted executables survive process restarts.
+                   workloads and persists the winners
     tune_cache_dir — tune-cache root (default ``$REPRO_TUNE_CACHE_DIR`` or
-                   ``~/.cache/repro-tune``)
+                   ``<checkout>/.cache/tune``)
     tune_repeats — best-of-N repeats per candidate in "search" mode
     use_fusion   — cross-segment fusion (lowering/fusion.py): lower
                    residual Add/pool/concat/bipolar boundary ops into fused
@@ -476,10 +473,18 @@ def compile_graph(graph: QonnxGraph, *, run_cleanup: bool = True,
     Every compile records wall time and plan-shape gauges (segment counts
     per fused kind, fused-node count, integer-requant coverage, tune-cache
     hit/miss counters) into the process-wide ``repro.obs`` default
-    registry under ``model=graph.name``.
+    registry under ``model=graph.name``.  On an accelerator every compile
+    also turns on the JAX persistent compilation cache at its fixed
+    directory (``tune.configure_jax_persistent_cache``), so a restarted
+    server finds its executables again.  The CPU backend (tests,
+    interpreted rehearsals) persists nothing: XLA:CPU warns on every reload
+    of its own cached executables.
     """
     t_compile0 = time.perf_counter()
     from repro.kernels._blocks import resolve_interpret
+    from repro.tune.cache import configure_jax_persistent_cache
+    if jax.default_backend() != "cpu":
+        configure_jax_persistent_cache()
     interpret = resolve_interpret(interpret)
     if isinstance(mesh, str):
         if mesh != "auto":

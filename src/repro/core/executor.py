@@ -21,6 +21,7 @@ parity oracle — see tests/test_compile.py).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -179,7 +180,11 @@ register_op("Add")(_binary(jnp.add))
 register_op("Sub")(_binary(jnp.subtract))
 register_op("Mul")(_binary(jnp.multiply))
 register_op("Div")(_binary(jnp.divide))
-register_op("MatMul")(_binary(jnp.matmul))
+# f32 contractions pin full precision: on a TPU the default is one bf16
+# pass, and the oracle must stay the plain fp32 reference
+_HIGHEST = jax.lax.Precision.HIGHEST
+register_op("MatMul")(_binary(functools.partial(jnp.matmul,
+                                                precision=_HIGHEST)))
 register_op("Pow")(_binary(jnp.power))
 
 
@@ -191,7 +196,7 @@ def _gemm(node, a, b, c=None):
         a = a.T
     if node.attrs.get("transB", 0):
         b = b.T
-    y = alpha * (a @ b)
+    y = alpha * jnp.matmul(a, b, precision=_HIGHEST)
     if c is not None:
         y = y + beta * c
     return y
@@ -328,7 +333,8 @@ def _conv(node, x, w, b=None):
         dn = jax.lax.conv_dimension_numbers(x.shape, w.shape, _conv_dims("NCHW", nsp))
     y = jax.lax.conv_general_dilated(
         x, w.astype(x.dtype), strides, pad_pairs, lhs_dilation=None,
-        rhs_dilation=dil, dimension_numbers=dn, feature_group_count=group)
+        rhs_dilation=dil, dimension_numbers=dn, feature_group_count=group,
+        precision=_HIGHEST)
     if b is not None:
         c_axis = 1 if layout == "NCHW" else x.ndim - 1
         shape = [1] * y.ndim

@@ -8,7 +8,7 @@ rules (matmul, conv, activation QDQ); downstream code registers more with
 from .base import (  # noqa: F401
     LoweringContext, LoweringRule, Match, Segment, col_scale,
     conv_channel_scale, get_rule, iter_rules, register_rule, rules_for,
-    scalar, select_accumulator, sole_consumer, static_value,
+    scalar, select_accumulator, select_operand, sole_consumer, static_value,
     unregister_rule)
 from .weights import (  # noqa: F401
     KernelMatch, QuantWeight, chain_absorbable, resolve_quant_weight)

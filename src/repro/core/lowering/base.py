@@ -272,3 +272,32 @@ def select_accumulator(ctx: LoweringContext, node: Node, match,
     match.acc_bits = bits
     if exact_int32:
         match.acc_dtype = jnp.int32
+
+
+_INT8_RANGE = (-128.0, 127.0)
+_F32_EXACT_BITS = 25    # signed sums within +-2**24 are exact in f32
+
+
+def select_operand(ctx: LoweringContext, match) -> None:
+    """Pick the MXU operand type of the kernel's activation input.
+
+    Runs after ``select_accumulator`` / ``select_requant``.  On an int32
+    accumulator, an operand that is integral within int8 (``q - z`` on
+    the integer-requant path, the activation values otherwise) travels as
+    int8 codes and multiplies on the MXU's int8 path, exact in int32.  A
+    wider operand multiplies in f32, which is exact only while every sum
+    stays within 2**24 — the requant proof guarantees that; elsewhere a
+    wider bound keeps the f32 accumulator.  Mutates ``match.x_int8`` /
+    ``match.acc_dtype`` in place.
+    """
+    if ctx.analysis is None or jnp.dtype(match.acc_dtype) != jnp.int32:
+        return
+    if match.requant is not None:
+        lo, hi = match.requant.x_range
+    else:
+        r = ctx.analysis.range(match.x)
+        lo, hi = r.lo, r.hi
+    if _INT8_RANGE[0] <= lo and hi <= _INT8_RANGE[1]:
+        match.x_int8 = True
+    elif match.acc_bits is None or match.acc_bits > _F32_EXACT_BITS:
+        match.acc_dtype = jnp.float32

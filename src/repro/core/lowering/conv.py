@@ -57,7 +57,7 @@ import numpy as np
 from ..graph import Node, QonnxGraph
 from .base import (LoweringContext, LoweringRule, Segment, conv_channel_scale,
                    conv_out_rows, register_rule, select_accumulator,
-                   sole_consumer, static_value)
+                   select_operand, sole_consumer, static_value)
 from .qdq import stage_qdq_epilogue, static_act_quant_params
 from .requant import select_requant
 from .weights import (KernelMatch, QuantWeight, chain_absorbable,
@@ -210,6 +210,7 @@ class QuantConvRule(LoweringRule):
                        w_absum=np.abs(nb.qw.w_int.astype(np.int64))
                        .sum(axis=(1, 2, 3)),
                        relu=nb.relu, act=nb.act)
+        select_operand(ctx, m)
         if getattr(ctx, "use_fusion", True):
             from . import fusion
             m.carrier_accepts = (m.x,)
@@ -248,6 +249,7 @@ class QuantConvRule(LoweringRule):
         # kernel's IntRequant epilogue; only the exact x / s_x remains here
         relu = m.relu and m.requant is None
         in_scale = None if m.requant is None else m.requant.in_scale
+        x_int8 = m.x_int8
         # integer-boundary output off the requant path: the kernel emitted
         # s_a*(q - z_a) with a proven power-of-two s_a = 2**-T_a, so the
         # codes are recovered exactly as q = y*2**T_a + z_a
@@ -262,6 +264,8 @@ class QuantConvRule(LoweringRule):
                 x = fusion.boundary_values(x, cin)
             if in_scale is not None:
                 x = x.astype(jnp.float32) / in_scale
+            if x_int8:          # proven integral within int8: exact cast
+                x = x.astype(jnp.int8)
             y = conv(x, consts[w_key], consts[s_key],
                      consts[b_key] if b_key else None)
             if relu:

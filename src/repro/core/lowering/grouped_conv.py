@@ -45,7 +45,7 @@ import numpy as np
 
 from ..graph import Node, QonnxGraph
 from .base import (LoweringContext, LoweringRule, Segment, conv_out_rows,
-                   register_rule, select_accumulator)
+                   register_rule, select_accumulator, select_operand)
 from .conv import ActQuantParams, QuantConvMatch, match_conv_common
 from .qdq import stage_qdq_epilogue
 from .requant import select_requant
@@ -132,6 +132,8 @@ class GroupedConvRule(LoweringRule):
                        w_absum=np.abs(nb.qw.w_int.astype(np.int64))
                        .sum(axis=(1, 2, 3)),
                        relu=nb.relu, act=nb.act)
+        if not depthwise:        # the depthwise kernel never uses the MXU
+            select_operand(ctx, m)
         if getattr(ctx, "use_fusion", True):
             from . import fusion
             m.carrier_accepts = (m.x,)
@@ -212,6 +214,7 @@ class GroupedConvRule(LoweringRule):
                         cout)
                 env[out_name] = y
         else:
+            x_int8 = m.x_int8
             conv = functools.partial(
                 kernel_ops.quant_grouped_conv2d, groups=m.group,
                 kernel_shape=m.kernel_shape, strides=m.strides, pads=m.pads,
@@ -225,6 +228,8 @@ class GroupedConvRule(LoweringRule):
                     x = fusion.boundary_values(x, cin)
                 if in_scale is not None:
                     x = x.astype(jnp.float32) / in_scale
+                if x_int8:      # proven integral within int8: exact cast
+                    x = x.astype(jnp.int8)
                 y = conv(x, consts[w_key], consts[s_key],
                          consts[b_key] if b_key else None)
                 if relu:

@@ -20,8 +20,8 @@ import numpy as np
 
 from ..graph import Node, QonnxGraph
 from .base import (LoweringContext, LoweringRule, Segment, col_scale,
-                   register_rule, select_accumulator, sole_consumer,
-                   static_value, tensor_rows)
+                   register_rule, select_accumulator, select_operand,
+                   sole_consumer, static_value, tensor_rows)
 from .requant import select_requant
 from .weights import (KernelMatch, chain_absorbable, resolve_quant_weight,
                       stage_kernel_carriers)
@@ -61,6 +61,7 @@ def make_matmul_segment(idx: int, m: KernelMatch, consts: dict,
     # exact fp32 division — the true quotient is a representable integer
     # (select_requant proved it), and IEEE division is correctly rounded.
     in_scale = None if m.requant is None else m.requant.in_scale
+    x_int8 = m.x_int8
 
     def run(consts, env):
         x = env.get(x_name, consts.get(x_name))
@@ -70,6 +71,8 @@ def make_matmul_segment(idx: int, m: KernelMatch, consts: dict,
         x2 = x.reshape((-1, x.shape[-1])).astype(jnp.float32)
         if in_scale is not None:
             x2 = x2 / in_scale
+        if x_int8:              # proven integral within int8: exact cast
+            x2 = x2.astype(jnp.int8)
         y = kernel(x2, consts[w_key], consts[s_key],
                    consts[b_key] if b_key else None)
         env[out_name] = y.reshape(lead + (y.shape[-1],))
@@ -111,6 +114,7 @@ class QuantMatMulRule(LoweringRule):
             select_requant(ctx, g, node, m,
                            w_absum=np.abs(m.w_int.astype(np.int64))
                            .sum(axis=0))
+            select_operand(ctx, m)
             if getattr(ctx, "use_fusion", True):
                 # accept-only: the matmul dequantizes a carried activation
                 # on entry; it offers no codes (its epilogue stays as-is)

@@ -63,6 +63,7 @@ class RequantPlan:
     mult     — int32 ``M_x * M_w`` multipliers, () or per-channel (O,)
     spec     — static ``IntRequant`` (kernels/requant.py) for the epilogue
     acc_bits — minimal signed accumulator width of the ``q - z`` domain dot
+    x_range  — (lo, hi) of the ``q - z`` operand the kernel multiplies
     fp32_ops_eliminated — per-trace fp32 epilogue ops the path removes:
                the dequant multiply, the fused relu max, and the 6-op
                requant chain (div, add-zp, round, clamp, sub-zp, mul) all
@@ -73,6 +74,7 @@ class RequantPlan:
     spec: object
     acc_bits: int
     fp32_ops_eliminated: int
+    x_range: tuple
 
 
 def _scalar_int(a) -> Optional[int]:
@@ -208,6 +210,7 @@ def select_requant(ctx: LoweringContext, g: QonnxGraph, node: Node, match,
     match.requant = RequantPlan(
         in_scale=np.float32(np.asarray(s_x, np.float32).reshape(())),
         mult=mult.astype(np.int32), spec=IntRequant(**spec_kwargs),
-        acc_bits=acc_bits, fp32_ops_eliminated=eliminated)
+        acc_bits=acc_bits, fp32_ops_eliminated=eliminated,
+        x_range=(grid.int_lo - z, grid.int_hi - z))
     match.acc_dtype = jnp.int32
     match.acc_bits = acc_bits

@@ -42,6 +42,8 @@ class KernelMatch(Match):
     acc_dtype: object = jnp.float32   # analysis-selected accumulator
     acc_bits: Optional[int] = None    # minimal accumulator width (if proven)
     requant: Optional[object] = None  # proven RequantPlan (integer path)
+    x_int8: bool = False              # kernel operand travels as int8
+                                      # codes (base.select_operand)
     rows: Optional[int] = None        # leading M rows (autotuner bucketing)
     carrier_accepts: tuple = ()       # inputs the emitter can take as
                                       # integer boundary carriers
@@ -86,6 +88,7 @@ def stage_kernel_carriers(idx: int, m: KernelMatch, consts: dict, ctx,
     if m.bias is not None:
         consts[b_key] = jnp.asarray(m.bias, jnp.float32)
     meta = {"acc": jnp.dtype(m.acc_dtype).name,
+            "operand": "int8" if m.x_int8 else "f32",
             "requant_path": "int32" if m.requant is not None else "fp32"}
     if m.acc_bits is not None:
         meta["acc_bits"] = m.acc_bits

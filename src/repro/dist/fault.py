@@ -15,6 +15,7 @@ from collections import deque
 from dataclasses import dataclass, field
 
 import jax
+from jax.sharding import AxisType
 
 log = logging.getLogger("repro.dist.fault")
 
@@ -141,7 +142,8 @@ def elastic_mesh(prefer_model: int = 16):
     The model axis is the largest divisor of the device count that is
     <= ``prefer_model``; everything else becomes data parallelism.  On a
     1-device host this degenerates to a (1, 1) mesh, so the same launcher
-    runs everywhere.
+    runs everywhere.  Axes are ``Auto`` (GSPMD-propagated), so callers may
+    index sharded outputs, e.g. slice a padded batch back down.
     """
     n = jax.device_count()
     model = 1
@@ -149,4 +151,5 @@ def elastic_mesh(prefer_model: int = 16):
         if n % cand == 0:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

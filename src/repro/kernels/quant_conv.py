@@ -118,13 +118,21 @@ def extract_patches(x, kernel_shape, strides=(1, 1), pads=(0, 0, 0, 0),
     return p.reshape(n * oh * ow, c * kh * kw), (oh, ow)
 
 
+def mxu_operand(x):
+    """Activations as the matmul kernels take them: int8 codes unchanged
+    (the MXU's int8 path), anything else as f32."""
+    x = jnp.asarray(x)
+    return x if x.dtype == jnp.int8 else x.astype(jnp.float32)
+
+
 def quant_conv2d(x, w2, w_scale, bias=None, *, kernel_shape, strides=(1, 1),
                  pads=(0, 0, 0, 0), dilations=(1, 1), packed=False,
                  blocks=DEFAULT_BLOCKS, interpret=None,
                  out_dtype=jnp.float32, acc_dtype=jnp.float32, requant=None):
     """Fused quantized conv: im2col patches through the integer matmul kernels.
 
-    x        — (N, C, H, W) activations (any float dtype; cast to f32)
+    x        — (N, C, H, W) activations (any float dtype, cast to f32;
+               int8 codes pass through to the MXU's int8 path)
     w2       — im2col'd integer weights: (C·kH·kW, O) int8, or the int4
                packing thereof (C·kH·kW // 2, O) when ``packed``
     w_scale  — dequant scale, scalar or per-output-channel (O,)
@@ -133,7 +141,7 @@ def quant_conv2d(x, w2, w_scale, bias=None, *, kernel_shape, strides=(1, 1),
                then carries int32 multipliers (see ``quant_matmul``)
     Returns (N, O, OH, OW) in ``out_dtype``.
     """
-    x = jnp.asarray(x, jnp.float32)
+    x = mxu_operand(x)
     patches, (oh, ow) = extract_patches(x, kernel_shape, strides, pads,
                                         dilations)
     mm = quant_matmul_int4 if packed else quant_matmul

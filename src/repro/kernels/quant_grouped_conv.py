@@ -16,7 +16,9 @@ instead of dense-matmul reuse; this module is that dataflow on TPU:
     slice (M, Kg) contracts only against its own ``(Kg, Ng)`` weight block —
     no zero padding anywhere, carrier bytes and MACs are exactly the true
     contraction.  An int4 variant unpacks two-per-byte packed weights
-    inside the kernel (``pack_int4_grouped`` packs along each group's Kg).
+    inside the kernel (``pack_int4_grouped`` packs along each group's Kg,
+    pairing row r with row r + Kg/2 so each nibble plane meets a
+    contiguous half of the patches).
   * ``quant_depthwise_conv2d`` — the ``group=cin`` case has a K dimension
     of only ``kH·kW`` taps, far too skinny for the 128×128 MXU; it is a
     VPU multiply-reduce instead.  Channels ride the 128-wide lane axis,
@@ -44,9 +46,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._blocks import (resolve_interpret as _resolve_interpret,
                       round_up as _round_up)
-from .quant_conv import conv_tap_slices, extract_patches
+from .quant_conv import conv_tap_slices, extract_patches, mxu_operand
 from .quant_dequant import _round_kernel_body, _static_bounds
-from .quant_matmul import DEFAULT_BLOCKS, _unpack_lo_hi
+from .quant_matmul import DEFAULT_BLOCKS, _unpack_lo_hi, mxu_dot
+from .ref import pack_int4_ref, unpack_int4_ref
 from .requant import int_epilogue
 
 DEFAULT_DW_BLOCK = (256, 128)     # (bm rows, bc channels) — lane-axis = C
@@ -88,27 +91,18 @@ def pack_int4_grouped(wg):
     """Pack (G, Kg, Ng) int4-valued int8 into (G, Kg//2, Ng) carriers.
 
     Same nibble scheme as ``ref.pack_int4_ref`` applied per group: packed
-    row r holds original rows 2r (low nibble) and 2r+1 (high nibble).
+    row r holds original rows r (low nibble) and r + Kg//2 (high nibble).
     Each group's Kg must be even — the lowering rule only selects the int4
     path when ``(I/g)·kH·kW`` is.
     """
-    wg = jnp.asarray(wg)
-    assert wg.shape[1] % 2 == 0, "per-group K must be even for int4 packing"
-    lo = wg[:, 0::2].astype(jnp.uint8)
-    hi = wg[:, 1::2].astype(jnp.uint8)
-    return ((hi << 4) | (lo & 0xF)).astype(jnp.int8)
+    assert jnp.shape(wg)[1] % 2 == 0, \
+        "per-group K must be even for int4 packing"
+    return pack_int4_ref(wg, axis=1)
 
 
 def unpack_int4_grouped(wg_packed):
     """Inverse of ``pack_int4_grouped``: (G, Kg//2, Ng) -> (G, Kg, Ng)."""
-    wg_packed = jnp.asarray(wg_packed)
-    lo = (wg_packed.astype(jnp.int8) << 4) >> 4
-    hi = wg_packed.astype(jnp.int8) >> 4
-    g, k2, n = wg_packed.shape
-    out = jnp.zeros((g, k2 * 2, n), jnp.int8)
-    out = out.at[:, 0::2].set(lo)
-    out = out.at[:, 1::2].set(hi)
-    return out
+    return unpack_int4_ref(wg_packed, axis=1)
 
 
 def extract_depthwise_taps(x, kernel_shape, strides=(1, 1), pads=(0, 0, 0, 0),
@@ -141,24 +135,24 @@ def _pad3(a, rows: int, cols: int, value=0):
     return jnp.pad(a, ((0, 0), (0, pr), (0, pc)), constant_values=value)
 
 
-def _gqmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk, acc_dtype,
-                 packed, requant=None):
+def _gqmm_kernel(*refs, nk, acc_dtype, packed, requant=None):
+    if packed:
+        xlo_ref, xhi_ref, w_ref, s_ref, o_ref, acc_ref = refs
+    else:
+        x_ref, w_ref, s_ref, o_ref, acc_ref = refs
     k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(acc_dtype)               # (bm, bk)
     if packed:
-        lo, hi = _unpack_lo_hi(w_ref[0])         # each (bk//2, bn)
-        acc_ref[...] += jnp.dot(x[:, 0::2], lo.astype(acc_dtype),
-                                preferred_element_type=acc_dtype)
-        acc_ref[...] += jnp.dot(x[:, 1::2], hi.astype(acc_dtype),
-                                preferred_element_type=acc_dtype)
+        # packed row r holds rows r (lo) and r + Kg/2 (hi) of this group
+        lo, hi = _unpack_lo_hi(w_ref[0])         # each (bk2, bn)
+        acc_ref[...] += mxu_dot(xlo_ref[0], lo, acc_dtype)
+        acc_ref[...] += mxu_dot(xhi_ref[0], hi, acc_dtype)
     else:
-        acc_ref[...] += jnp.dot(x, w_ref[0].astype(acc_dtype),
-                                preferred_element_type=acc_dtype)
+        acc_ref[...] += mxu_dot(x_ref[0], w_ref[0], acc_dtype)
 
     @pl.when(k == nk - 1)
     def _finish():
@@ -189,9 +183,10 @@ def quant_grouped_matmul(xg, wg, w_scale, *, packed=False,
                          requant=None):
     """Per-group integer matmul: out[g] = xg[g] @ (scale[g] * wg[g]).
 
-    xg: (G, M, Kg) f32 per-group activations/patches;
+    xg: (G, M, Kg) f32 per-group activations/patches, or int8 codes;
     wg: (G, Kg, Ng) int8, or its per-group int4 packing (G, Kg//2, Ng)
-        when ``packed``;
+        when ``packed`` (``blocks[2]`` then counts unpacked rows per step,
+        half from each nibble plane);
     w_scale: scalar or (G·Ng,) group-major per-output-channel scale.
     requant: optional ``IntRequant`` — integer dyadic epilogue; ``w_scale``
     then carries int32 multipliers (acc_dtype must be int32).
@@ -205,31 +200,34 @@ def quant_grouped_matmul(xg, wg, w_scale, *, packed=False,
     gw, kw_rows, n = wg.shape
     assert gw == g, (xg.shape, wg.shape)
     assert kdim == (2 * kw_rows if packed else kw_rows), (xg.shape, wg.shape)
-    bm, bn, bk = (min(blocks[0], m), min(blocks[1], n), min(blocks[2], kdim))
-    if packed and bk % 2:
-        bk += 1
-    mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(kdim, bk)
-    xq = _pad3(xg, mp, kp)
-    wq = _pad3(wg, kp // 2 if packed else kp, np_)
+    bm, bn = min(blocks[0], m), min(blocks[1], n)
+    # bk: weight-carrier rows per K step (packed rows when packed)
+    bk = min((blocks[2] + 1) // 2 if packed else blocks[2], kw_rows)
+    mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(kw_rows, bk)
+    if packed:      # each half of Kg pads on its own (0x00 = zero nibbles)
+        xs = [_pad3(xg[:, :, :kw_rows], mp, kp),
+              _pad3(xg[:, :, kw_rows:], mp, kp)]
+    else:
+        xs = [_pad3(xg, mp, kp)]
+    wq = _pad3(wg, kp, np_)
     s_dtype = jnp.int32 if requant is not None else jnp.float32
     s3 = _pad3(_norm_group_scale(w_scale, g, n, s_dtype), 1, np_)
     grid = (g, mp // bm, np_ // bn, kp // bk)
+    x_spec = pl.BlockSpec((1, bm, bk), lambda gi, i, j, k: (gi, i, k))
 
     out = pl.pallas_call(
         functools.partial(_gqmm_kernel, nk=grid[3], acc_dtype=acc_dtype,
                           packed=packed, requant=requant),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda gi, i, j, k: (gi, i, k)),
-            pl.BlockSpec((1, bk // 2 if packed else bk, bn),
-                         lambda gi, i, j, k: (gi, k, j)),
+        in_specs=[x_spec] * len(xs) + [
+            pl.BlockSpec((1, bk, bn), lambda gi, i, j, k: (gi, k, j)),
             pl.BlockSpec((1, 1, bn), lambda gi, i, j, k: (gi, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda gi, i, j, k: (gi, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=interpret,
-    )(xq, wq, s3)
+    )(*xs, wq, s3)
     return out[:, :m, :n]
 
 
@@ -240,7 +238,8 @@ def quant_grouped_conv2d(x, wg, w_scale, bias=None, *, groups, kernel_shape,
                          requant=None):
     """Fused grouped quantized conv: per-group im2col onto the blocked kernel.
 
-    x        — (N, C, H, W) activations (cast to f32)
+    x        — (N, C, H, W) activations (cast to f32; int8 codes pass
+               through to the MXU's int8 path)
     wg       — per-group integer weights (G, Kg, Ng) int8 with
                Kg = (C/G)·kH·kW and Ng = O/G, or the per-group int4 packing
                (G, Kg//2, Ng) when ``packed`` (``grouped_weights`` /
@@ -251,7 +250,7 @@ def quant_grouped_conv2d(x, wg, w_scale, bias=None, *, groups, kernel_shape,
                then carries int32 multipliers (see ``quant_grouped_matmul``)
     Returns (N, O, OH, OW) in ``out_dtype``.
     """
-    x = jnp.asarray(x, jnp.float32)
+    x = mxu_operand(x)
     patches, (oh, ow) = extract_patches(x, kernel_shape, strides, pads,
                                         dilations)
     m, feat = patches.shape

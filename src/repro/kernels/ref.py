@@ -34,25 +34,23 @@ def quant_matmul_ref(x, w_int, w_scale, bias=None):
     return out
 
 
-def pack_int4_ref(w_int):
+def pack_int4_ref(w_int, axis=0):
     """Pack (K, N) int4-valued int8 into (K//2, N) int8 carriers.
 
-    Row 2k goes to the low nibble, row 2k+1 to the high nibble.
+    Row r goes to the low nibble and row r + K//2 to the high nibble, so
+    each nibble plane pairs with a contiguous half of the contraction.
+    ``axis`` names the K axis (the grouped carrier packs axis 1).
     """
-    lo = w_int[0::2].astype(jnp.int8)
-    hi = w_int[1::2].astype(jnp.int8)
+    lo, hi = jnp.split(jnp.asarray(w_int).astype(jnp.int8), 2, axis=axis)
     return ((hi.astype(jnp.uint8) << 4) | (lo.astype(jnp.uint8) & 0xF)).astype(jnp.int8)
 
 
-def unpack_int4_ref(w_packed):
+def unpack_int4_ref(w_packed, axis=0):
     """Inverse of pack_int4_ref: (K//2, N) int8 -> (K, N) int4-valued int8."""
-    lo = (w_packed.astype(jnp.int8) << 4) >> 4          # sign-extend low nibble
-    hi = w_packed.astype(jnp.int8) >> 4                 # arithmetic shift
-    K2, N = w_packed.shape
-    out = jnp.zeros((K2 * 2, N), jnp.int8)
-    out = out.at[0::2].set(lo.astype(jnp.int8))
-    out = out.at[1::2].set(hi.astype(jnp.int8))
-    return out
+    w_packed = jnp.asarray(w_packed).astype(jnp.int8)
+    lo = (w_packed << 4) >> 4                           # sign-extend low nibble
+    hi = w_packed >> 4                                  # arithmetic shift
+    return jnp.concatenate([lo, hi], axis=axis)
 
 
 def quant_matmul_int4_ref(x, w_packed, w_scale, bias=None):

@@ -203,7 +203,7 @@ def main():
                          "keeps module defaults (default: cached)")
     ap.add_argument("--tune-cache-dir", metavar="PATH", default=None,
                     help="tune-cache root (default $REPRO_TUNE_CACHE_DIR "
-                         "or ~/.cache/repro-tune)")
+                         "or <checkout>/.cache/tune)")
     # observability
     ap.add_argument("--metrics-port", type=int, default=None, metavar="PORT",
                     help="expose the metrics registry over HTTP: GET "

@@ -12,7 +12,7 @@ memory:
                     backend) and ``BlockConfig`` (chosen tiling +
                     provenance).
   * ``cache``     — ``TuneCache``: atomic, corrupt-tolerant on-disk store
-                    (``~/.cache/repro-tune`` / ``$REPRO_TUNE_CACHE_DIR``)
+                    (``<checkout>/.cache/tune`` / ``$REPRO_TUNE_CACHE_DIR``)
                     of per-kernel entries and per-graph manifests, keyed by
                     content hashes that fold in ``kernel_version()``; plus
                     ``configure_jax_persistent_cache`` so jitted

@@ -267,8 +267,8 @@ class Autotuner:
 
         if sig.family == "matmul":
             x = rng.randn(m, k).astype(np.float32)
-            if int_requant:
-                x = np.round(x * 8.0)
+            if int_requant:     # int8 codes, as the lowering feeds the MXU
+                x = np.round(x * 8.0).astype(np.int8)
             w = rng.randint(-7, 8, size=(k, n)).astype(np.int8)
             if int_requant:
                 scale = np.ones((n,), np.int32)
@@ -287,7 +287,7 @@ class Autotuner:
             g = max(1, sig.groups)
             xg = rng.randn(g, m, k).astype(np.float32)
             if int_requant:
-                xg = np.round(xg * 8.0)
+                xg = np.round(xg * 8.0).astype(np.int8)
             wg = rng.randint(-7, 8, size=(g, k, n)).astype(np.int8)
             if int_requant:
                 scale = np.ones((g * n,), np.int32)

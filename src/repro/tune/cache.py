@@ -1,6 +1,6 @@
 """Content-addressed on-disk tune cache + JAX persistent-cache wiring.
 
-Layout (default root ``~/.cache/repro-tune``, overridable with the
+Layout (default root ``<checkout>/.cache/tune``, overridable with the
 ``REPRO_TUNE_CACHE_DIR`` env var or the ``tune_cache_dir=`` argument):
 
     <root>/kernels/<sha>.json    one entry per KernelSig x kernel-version:
@@ -11,9 +11,12 @@ Layout (default root ``~/.cache/repro-tune``, overridable with the
     <root>/graphs/<sha>.json     per-graph manifest: sig-key -> blocks, so
                                  a warm reload answers every segment from
                                  ONE file read instead of one per segment.
-    <root>/jax-cache/            the JAX persistent compilation cache —
-                                 jitted executables survive process
-                                 restarts (``configure_jax_persistent_cache``).
+
+The JAX persistent compilation cache lives apart from it, at
+``$JAX_COMPILATION_CACHE_DIR`` when set and ``<checkout>/.cache/jax``
+otherwise (``configure_jax_persistent_cache``, called by every
+``compile_graph``): a fixed path, because the path is part of what JAX
+keys a cached executable by.
 
 Keys are content hashes:
 
@@ -39,14 +42,21 @@ import functools
 import glob
 import hashlib
 import json
+import logging
 import os
 import tempfile
 from typing import Optional
 
 from .config import BlockConfig, KernelSig
 
+log = logging.getLogger("repro.tune")
+
 _ENV_VAR = "REPRO_TUNE_CACHE_DIR"
-_DEFAULT_ROOT = os.path.join("~", ".cache", "repro-tune")
+# <checkout>/.cache: src/repro/tune/cache.py is three levels below it
+_CACHE_HOME = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))))), ".cache")
+_DEFAULT_ROOT = os.path.join(_CACHE_HOME, "tune")
+JAX_CACHE_DIR = os.path.join(_CACHE_HOME, "jax")
 
 
 @functools.lru_cache(maxsize=1)
@@ -122,15 +132,11 @@ def _read_json(path: str) -> Optional[dict]:
 class TuneCache:
     """The on-disk tiling store (see module docstring for layout/keys)."""
 
-    def __init__(self, root: Optional[str] = None, *,
-                 persist_executables: bool = True):
+    def __init__(self, root: Optional[str] = None):
         root = root or os.environ.get(_ENV_VAR) or _DEFAULT_ROOT
         self.root = os.path.abspath(os.path.expanduser(root))
         self.kernels_dir = os.path.join(self.root, "kernels")
         self.graphs_dir = os.path.join(self.root, "graphs")
-        if persist_executables:
-            configure_jax_persistent_cache(
-                os.path.join(self.root, "jax-cache"))
 
     # -- kernel entries (shared across graphs) -------------------------
     def _kernel_path(self, sig: KernelSig) -> str:
@@ -193,29 +199,32 @@ _jax_cache_configured: list = []            # once-per-process latch
 
 def configure_jax_persistent_cache(
         cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+    """Turn on JAX's persistent compilation cache at a fixed directory.
 
     Jitted executables then survive process restarts — the second serve of
-    the same model skips XLA compilation entirely.  Explicit
-    ``JAX_COMPILATION_CACHE_DIR`` in the environment wins over our default;
-    the thresholds are dropped to 0/-1 because quantized-inference
+    the same model skips XLA compilation entirely.  A set
+    ``JAX_COMPILATION_CACHE_DIR`` is used as JAX reads it, and no other
+    directory is set; otherwise ``cache_dir`` or ``<checkout>/.cache/jax``.
+    The thresholds are dropped to 0/-1 because quantized-inference
     executables are small but recompiled often.  Once per process: JAX
     ignores config churn after first use, so later calls return the
-    already-configured dir.  Any failure degrades to in-memory-only
-    compilation (returns None) — never an error.
+    already-configured dir.  A directory that cannot be set up is logged
+    with its cause and compilation stays in-memory (returns None).
     """
     if _jax_cache_configured:
         return _jax_cache_configured[0]
-    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir or \
-        os.path.join(os.path.expanduser(
-            os.environ.get(_ENV_VAR) or _DEFAULT_ROOT), "jax-cache")
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = env_dir or cache_dir or JAX_CACHE_DIR
     try:
         import jax
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        if not env_dir:
+            jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     except Exception:
+        log.warning("JAX persistent compilation cache at %s could not be "
+                    "set up; compiling in memory only", path, exc_info=True)
         _jax_cache_configured.append(None)
         return None
     _jax_cache_configured.append(path)

@@ -45,6 +45,7 @@ def test_multidevice_suite_in_subprocess():
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=8").strip()
     env["REPRO_MULTIDEV_INNER"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"       # a child must never contend for a chip
     env.setdefault("PYTHONPATH", "src")
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q", __file__],

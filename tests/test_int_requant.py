@@ -237,7 +237,8 @@ def test_integer_epilogue_emits_no_fp32_requant_ops():
     rq = IntRequant(shift=10, relu=True, has_act=True, act_shift=6,
                     act_zp=1, act_lo=-8, act_hi=7, act_out_shift=4,
                     rounding_mode="ROUND")
-    x = jnp.zeros((8, 16), jnp.float32)
+    # int8 codes: the operand the lowering feeds an integer-requant segment
+    x = jnp.zeros((8, 16), jnp.int8)
     w = jnp.zeros((16, 4), jnp.int8)
     mult = jnp.ones((4,), jnp.int32)
     fn = functools.partial(kernel_ops.quant_matmul, acc_dtype=jnp.int32,
@@ -282,10 +283,39 @@ def test_zoo_full_integer_coverage_and_bit_exact(name, shape):
     assert stats["fp32_segments"] == 0, plan.describe()
     assert stats["coverage"] == 1.0 and stats["kernel_segments"] >= 4
     assert stats["fp32_ops_eliminated"] > 0
+    # every kernel multiplies int8 codes on the MXU's int8 path
+    assert {s.meta["operand"] for s in plan.segments
+            if "operand" in s.meta} == {"int8"}, plan.describe()
     x = np.random.RandomState(0).randn(*shape).astype(np.float32)
     out = np.asarray(plan({"x": x})[plan.graph.output_names[0]])
     np.testing.assert_array_equal(_oracle(g, x), out,
                                   err_msg=plan.describe())
+
+
+@pytest.mark.parametrize("a_bits,k,operand,acc", [
+    (8, 512, "int8", "int32"),      # codes fit int8: the MXU int8 path
+    (16, 8, "f32", "int32"),        # wide codes, sums < 2**24: exact in f32
+    (16, 512, "f32", "float32"),    # sums may pass 2**24: f32 accumulator
+], ids=["int8", "wide_exact", "wide_f32_acc"])
+def test_kernel_operand_selection(a_bits, k, operand, acc):
+    """select_operand feeds the MXU int8 codes only when they fit, and
+    keeps an int32 accumulator on f32 operands only while it is exact."""
+    from repro.core import GraphBuilder
+    rng = np.random.RandomState(a_bits + k)
+    b = GraphBuilder("operand")
+    h = b.quant(b.add_input("x", (4, k)), 1.0, 0.0, a_bits)  # integral
+    w = b.quant(b.add_initializer("w", rng.randn(k, 6).astype(np.float32)),
+                0.5, 0.0, 4, narrow=True)
+    (y,) = b.add_node("MatMul", [h, w], 1)
+    b.mark_output(y)
+    g = b.build()
+    plan = compile_graph(g)
+    (seg,) = [s for s in plan.segments if s.kind.startswith("quant_matmul")]
+    assert (seg.meta["operand"], seg.meta["acc"]) == (operand, acc)
+    lim = 2 ** (a_bits - 1)
+    x = rng.randint(-lim, lim, size=(4, k)).astype(np.float32)
+    out = np.asarray(plan({"x": x})[plan.graph.output_names[0]])
+    np.testing.assert_allclose(_oracle(g, x), out, rtol=1e-6)
 
 
 def test_use_integer_requant_false_restores_fp32_path():

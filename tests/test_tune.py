@@ -15,6 +15,7 @@ Covers the ISSUE-8 acceptance surface:
     backend-derived interpret default (kernels._blocks).
 """
 import json
+import logging
 import os
 import subprocess
 import sys
@@ -31,7 +32,7 @@ from repro.tune import (Autotuner, BlockConfig, KernelSig, TuneCache,
 
 def _cache(tmp_path):
     """A TuneCache rooted in the test tmp dir, JAX-cache wiring off."""
-    return TuneCache(str(tmp_path / "tune"), persist_executables=False)
+    return TuneCache(str(tmp_path / "tune"))
 
 
 def _mlp(seed=0, dims=(2, 12, 10, 6), w_bits=4, a_bits=4, scale=0.0973):
@@ -135,10 +136,10 @@ def test_corrupt_entries_recover_as_misses(tmp_path):
 
 def test_env_var_overrides_default_root(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_TUNE_CACHE_DIR", str(tmp_path / "env-root"))
-    cache = TuneCache(persist_executables=False)
+    cache = TuneCache()
     assert cache.root == str(tmp_path / "env-root")
     # an explicit root still wins over the env var
-    cache = TuneCache(str(tmp_path / "arg-root"), persist_executables=False)
+    cache = TuneCache(str(tmp_path / "arg-root"))
     assert cache.root == str(tmp_path / "arg-root")
 
 
@@ -147,7 +148,7 @@ def test_concurrent_writers_last_wins_whole_file(tmp_path):
     prog = """
 import sys
 from repro.tune import TuneCache, KernelSig
-cache = TuneCache(sys.argv[1], persist_executables=False)
+cache = TuneCache(sys.argv[1])
 sig = KernelSig(family="matmul", m=128, n=64, k=64)
 for _ in range(100):
     cache.store_kernel(sig, tuple(int(b) for b in sys.argv[2:]))
@@ -162,7 +163,7 @@ for _ in range(100):
         env=env) for blocks in [(128, 64, 64), (64, 64, 64)]]
     for p in procs:
         assert p.wait(timeout=120) == 0
-    got = TuneCache(root, persist_executables=False).lookup_kernel(
+    got = TuneCache(root).lookup_kernel(
         KernelSig(family="matmul", m=128, n=64, k=64))
     assert got is not None
     assert got.blocks in ((128, 64, 64), (64, 64, 64))
@@ -353,3 +354,39 @@ def test_configure_jax_persistent_cache_is_latched(tmp_path):
     from repro.tune import configure_jax_persistent_cache
     first = configure_jax_persistent_cache(str(tmp_path / "jax"))
     assert configure_jax_persistent_cache(str(tmp_path / "other")) == first
+
+
+@pytest.mark.parametrize("from_env", [False, True], ids=["fixed", "env"])
+def test_jax_cache_dir_is_fixed_or_from_env(tmp_path, from_env):
+    """Unset, the JAX cache lives at <checkout>/.cache/jax; a set
+    JAX_COMPILATION_CACHE_DIR is used and no other directory is set.
+    A fresh process each, since the configuration latches per process."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prog = ("import jax\n"
+            "from repro.tune import configure_jax_persistent_cache as c\n"
+            "print(c())\nprint(jax.config.jax_compilation_cache_dir)\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(root, ".cache", "jax")
+    if from_env:
+        want = str(tmp_path / "env-jax")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    proc = subprocess.run([sys.executable, "-c", prog], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == [want, want]
+    assert os.path.isdir(want)
+
+
+def test_jax_cache_setup_failure_is_logged(tmp_path, monkeypatch, caplog):
+    from repro.tune import cache as cache_mod
+    monkeypatch.setattr(cache_mod, "_jax_cache_configured", [])
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    blocker = tmp_path / "a-file"
+    blocker.write_text("not a directory")
+    with caplog.at_level(logging.WARNING, logger="repro.tune"):
+        got = cache_mod.configure_jax_persistent_cache(str(blocker / "jax"))
+    assert got is None
+    assert "could not be set up" in caplog.text
