@@ -27,10 +27,7 @@ the ``use_fusion=False`` plan).  Each per-model JSON record also carries
 Per model the JSON record also carries ``requant``: the plan's
 integer-path coverage (``CompiledPlan.requant_stats``) plus the measured
 epilogue speedup vs the same plan compiled with
-``use_integer_requant=False`` (the fp32 dequant->round->requant chain) —
-and ``profile``: the per-segment measured table (``CompiledPlan.profile``
-joined with the analysis cost report: ms / MACs/s / minimal-vs-achieved
-bytes / requant path per fused segment).
+``use_integer_requant=False`` (the fp32 dequant->round->requant chain).
 """
 from __future__ import annotations
 
@@ -123,10 +120,6 @@ def run_detailed(cases=None) -> tuple[list[str], dict]:
             # cross-segment fusion census: fused boundary segments, integer
             # carriers, inter-segment bytes saved per call vs fp32
             "fusion": plan.fusion_stats(),
-            # per-segment measured profile (ms, MACs/s, bytes, requant path
-            # per fused segment joined with the analysis cost report)
-            "profile": plan.profile(
-                {"x": x}, repeats=5).to_json(),
         }
     return rows, records
 

@@ -6,25 +6,13 @@
 Prints the top-N collectives by result bytes with their HLO lines — the
 "profile" of the dry-run methodology (no real hardware): every hillclimb
 hypothesis starts from this list.
-
-Compiled-graph segment profiling (measured, not dry-run):
-
-  PYTHONPATH=src python -m benchmarks.diagnose --profile CNV-w1a1 \\
-      [--repeats 20] [--bw-gbps 819] [--batch 1]
-
-Times every fused segment of the zoo model's compiled plan
-(``CompiledPlan.profile``) and prints the measured-ms / MACs/s /
-minimal-vs-achieved-bytes / requant table with the roofline column.
 """
 import os
-import sys
 
-if "--profile" not in sys.argv:
-    # the collective dry-run needs a big fake device mesh; the measured
-    # --profile path must run on the real (single-device) backend
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=512 "
-        + os.environ.get("REPRO_EXTRA_XLA_FLAGS", ""))
+# the collective dry-run needs a big fake device mesh
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=512 "
+    + os.environ.get("REPRO_EXTRA_XLA_FLAGS", ""))
 
 # ruff: noqa: E402
 import argparse
@@ -49,26 +37,6 @@ def top_collectives(hlo: str, n=15):
     return rows[:n]
 
 
-def profile_model(args) -> None:
-    """--profile MODEL: measured per-segment table for a zoo graph."""
-    import numpy as np
-
-    from repro.core.compile import compile_graph
-    from repro.models import zoo
-
-    g = zoo.ZOO[args.profile]()
-    plan = compile_graph(g)
-    x = None
-    if args.batch != 1:
-        shape = (args.batch,) + tuple(
-            1 if d is None else int(d) for d in g.inputs[0].shape)[1:]
-        x = np.random.RandomState(0).randn(*shape).astype(np.float32)
-    prof = plan.profile(x, repeats=args.repeats, bw_gbps=args.bw_gbps)
-    print(plan.describe())
-    print()
-    print(prof.table())
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
@@ -79,25 +47,10 @@ def main():
     ap.add_argument("--microbatches", type=int, default=4)
     ap.add_argument("--top", type=int, default=15)
     ap.add_argument("--quant", default="w8a8")
-    # measured segment profiling of a compiled zoo graph
-    ap.add_argument("--profile", metavar="MODEL",
-                    help="print the per-segment measured profile of a zoo "
-                         "model's compiled plan instead of the dry-run "
-                         "collective diagnostics")
-    ap.add_argument("--repeats", type=int, default=20,
-                    help="--profile timing repeats per segment (best-of)")
-    ap.add_argument("--bw-gbps", type=float, default=None,
-                    help="--profile roofline peak memory bandwidth in GB/s "
-                         "(e.g. 819 for the roofline.py HBM model)")
-    ap.add_argument("--batch", type=int, default=1,
-                    help="--profile input batch size")
     args = ap.parse_args()
 
-    if args.profile:
-        profile_model(args)
-        return
     if not args.arch or not args.shape:
-        ap.error("--arch and --shape are required (unless --profile)")
+        ap.error("--arch and --shape are required")
 
     from repro.launch.dryrun import arch_config, collective_bytes, lower_cell
     from repro.launch.mesh import make_production_mesh

@@ -111,12 +111,17 @@ class CompiledPlan:
             help="plan body traces (once per new input shape under jit)",
             labels={"model": self.graph.name})
 
+        # each segment's ops carry its scope in their HLO op_name (compile-
+        # time metadata only), so a device trace's ops join to segments
+        scopes = [f"seg{i:02d}_{seg.kind}" for i, seg in enumerate(segments)]
+
         def plan(consts, inputs):
             trace_cell[0] += 1
             m_retrace.inc()
             env = dict(inputs)
-            for seg in segments:
-                seg.run(consts, env)
+            for scope, seg in zip(scopes, segments):
+                with jax.named_scope(scope):
+                    seg.run(consts, env)
             # graph outputs may be compile-time constants (folded subgraphs)
             return {name: env.get(name, consts.get(name))
                     for name in output_names}
@@ -373,17 +378,6 @@ class CompiledPlan:
                 out["default_segments"] += 1
         out.update(self.tune_stats)
         return out
-
-    def profile(self, x=None, **kw):
-        """Per-segment measured profile (opt-in; see ``repro.obs.profile``).
-
-        Times each fused segment with its own ``block_until_ready`` (best of
-        ``repeats``) and joins the rows with the analysis cost report —
-        measured ms, MACs/s, minimal-vs-achieved bytes, requant path.
-        Returns a ``PlanProfile`` (``.table()`` / ``.to_json()``).
-        """
-        from repro.obs.profile import profile_plan
-        return profile_plan(self, x, **kw)
 
     def describe(self) -> str:
         head = (f"CompiledPlan({self.graph.name}): {len(self.segments)} "

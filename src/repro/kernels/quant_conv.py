@@ -31,6 +31,7 @@ NCHW, so the segment slots into the graph exactly where the Conv node was.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -94,13 +95,15 @@ def conv_tap_slices(x, kernel_shape, strides=(1, 1), pads=(0, 0, 0, 0),
     return taps, (oh, ow)
 
 
+@jax.named_scope("im2col")
 def extract_patches(x, kernel_shape, strides=(1, 1), pads=(0, 0, 0, 0),
                     dilations=(1, 1)):
     """Unfold NCHW ``x`` into an im2col patch matrix.
 
     Returns ``(patches, (OH, OW))`` where patches has shape
     (N·OH·OW, C·kH·kW), feature axis ordered (c, kh, kw) with c slowest —
-    matching ``im2col_weights``.
+    matching ``im2col_weights``.  Its ops carry the ``im2col`` named scope
+    in their HLO metadata, which the benchmark's trace reduction reads.
     """
     n, c, h, w = x.shape
     kh, kw = (int(v) for v in kernel_shape)

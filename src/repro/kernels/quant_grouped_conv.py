@@ -105,6 +105,7 @@ def unpack_int4_grouped(wg_packed):
     return unpack_int4_ref(wg_packed, axis=1)
 
 
+@jax.named_scope("im2col")
 def extract_depthwise_taps(x, kernel_shape, strides=(1, 1), pads=(0, 0, 0, 0),
                            dilations=(1, 1)):
     """Unfold NCHW ``x`` into per-tap channel-minor slices.
@@ -114,7 +115,8 @@ def extract_depthwise_taps(x, kernel_shape, strides=(1, 1), pads=(0, 0, 0, 0),
     (``quant_conv.conv_tap_slices`` is the shared unfold geometry), but the
     channel axis stays whole (moved to the minor/lane position) instead of
     being folded into a dense feature axis — depthwise never mixes
-    channels, so there is nothing to contract across.
+    channels, so there is nothing to contract across.  Under the
+    ``im2col`` named scope, as ``extract_patches`` is.
     """
     n, c, h, w = x.shape
     kh, kw = (int(v) for v in kernel_shape)
@@ -257,7 +259,8 @@ def quant_grouped_conv2d(x, wg, w_scale, bias=None, *, groups, kernel_shape,
     kg = feat // groups
     # channel is the slowest feature axis, so group gi's columns are the
     # contiguous slice [gi·Kg, (gi+1)·Kg): one reshape, no gather
-    xg = jnp.transpose(patches.reshape(m, groups, kg), (1, 0, 2))
+    with jax.named_scope("im2col"):
+        xg = jnp.transpose(patches.reshape(m, groups, kg), (1, 0, 2))
     y = quant_grouped_matmul(xg, wg, w_scale, packed=packed, blocks=blocks,
                              interpret=interpret, out_dtype=out_dtype,
                              acc_dtype=acc_dtype,
@@ -351,7 +354,8 @@ def quant_depthwise_conv2d(x, w_taps, w_scale, bias=None, act_scale=None,
     bm, bc = min(block[0], m), min(block[1], c)
     mp, cp = _round_up(m, bm), _round_up(c, bc)
     if mp != m or cp != c:
-        taps = jnp.pad(taps, ((0, 0), (0, mp - m), (0, cp - c)))
+        with jax.named_scope("im2col"):
+            taps = jnp.pad(taps, ((0, 0), (0, mp - m), (0, cp - c)))
     w2 = jnp.asarray(w_taps)
     if cp != c:
         w2 = jnp.pad(w2, ((0, 0), (0, cp - c)))

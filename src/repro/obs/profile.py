@@ -1,57 +1,31 @@
-"""Per-segment kernel profiling of a ``CompiledPlan``.
+"""The shared best-of-N timing harness.
 
-The compiled tier's whole point is the fused segments — but the jitted
-plan is one opaque callable, so nothing attributes wall time to the
-segments it fuses.  ``profile_plan`` re-runs the plan **segment by
-segment**, jitting each segment's ``run`` closure on its own and timing it
-with a per-segment ``block_until_ready`` amortized over repeat calls (best
-of N, interleaved warmup), then **joins** the measurements with the
-analysis tier's cost report (``repro.analysis.infer_cost``): every row
-carries measured ms, MACs and achieved MACs/s, the analysis' minimal
-memory-traffic estimate vs the bytes the segment actually moved, and the
-requantization path — the table the ROADMAP's autotuner will consume.
-
-The sum of per-segment times is compared against the fused whole-plan
-call (``plan_ms``): per-segment jit boundaries forbid cross-segment
-fusion, so ``sum_segments_ms`` is an *upper* bound on where time goes, and
-the gap is itself telemetry (how much XLA's cross-segment optimization
-buys).
-
-Usage::
-
-    from repro.obs import profile_plan
-    prof = profile_plan(plan, repeats=20)
-    print(prof.table())               # or prof.to_json()
-
-or ``plan.profile(...)`` / ``python -m benchmarks.diagnose --profile
-CNV-w1a1`` from the CLI.
+``time_fn`` / ``time_fns`` time host calls that end in
+``block_until_ready``: the tune tier's candidate search and the
+``benchmarks/`` scripts use them.  Where device time goes inside a plan
+is read from a profiler trace instead (``bench/trace_scopes.py``: each
+plan segment runs under its own named scope).
 """
 from __future__ import annotations
 
 import math
 import time
-from dataclasses import dataclass, field
-from typing import Optional
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-__all__ = ["SegmentProfile", "PlanProfile", "profile_plan", "time_fn",
-           "time_fns"]
+__all__ = ["time_fn", "time_fns"]
 
 
 def time_fn(fn, repeats: int = 5, *, warmup: int = 1) -> float:
     """Best-of-``repeats`` wall-clock seconds of ``fn()``.
 
-    The one best-of-N timing harness every consumer shares (this module's
-    per-segment profiling, benchmarks/bench_compile, benchmarks/bench_serve,
-    and the tune-tier candidate search).  Each call is forced with an
-    explicit ``jax.block_until_ready`` so async dispatch can't leak compute
-    out of the measurement; ``warmup`` unmeasured calls absorb trace +
+    The one best-of-N timing harness every consumer shares
+    (benchmarks/bench_compile, benchmarks/bench_serve, and the tune-tier
+    candidate search).  Each call is forced with an explicit
+    ``jax.block_until_ready`` so async dispatch can't leak compute out of
+    the measurement; ``warmup`` unmeasured calls absorb trace +
     compile.  Best-of (not mean) because scheduling noise is strictly
     additive — the minimum is the least-contaminated estimate.
     """
+    import jax
     for _ in range(warmup):
         jax.block_until_ready(fn())
     best = math.inf
@@ -67,6 +41,7 @@ def time_fns(fns, repeats: int = 5, *, warmup: int = 1) -> list[float]:
     rounds so load/frequency drift during the run cannot bias one
     contestant — the fair way to compare candidates (bench_serve's
     pipelined-vs-sync gate, the autotuner's tiling search)."""
+    import jax
     for fn in fns:
         for _ in range(warmup):
             jax.block_until_ready(fn())
@@ -77,199 +52,3 @@ def time_fns(fns, repeats: int = 5, *, warmup: int = 1) -> list[float]:
             jax.block_until_ready(fn())
             best[i] = min(best[i], time.perf_counter() - t0)
     return best
-
-
-@dataclass
-class SegmentProfile:
-    """One profiled segment joined with its analysis-report layers."""
-    index: int
-    kind: str
-    ops: str                          # "+"-joined op types
-    measured_ms: float                # best-of-repeats, block_until_ready
-    macs: int                         # per sample, from the cost report
-    macs_per_s: float                 # measured, batch-scaled
-    analysis_bytes: float             # analysis minimal traffic (roofline)
-    achieved_bytes: float             # bytes the segment actually moved
-    achieved_gbps: float
-    requant: Optional[str]            # "int32" / "fp32" / None
-    layers: list = field(default_factory=list)   # joined layer names
-    roofline_ms: Optional[float] = None          # analysis_bytes / peak BW
-    roofline_frac: Optional[float] = None        # roofline_ms / measured_ms
-
-    def to_json(self) -> dict:
-        return {
-            "segment": self.index, "kind": self.kind, "ops": self.ops,
-            "measured_ms": round(self.measured_ms, 4),
-            "macs": self.macs,
-            "macs_per_s": round(self.macs_per_s, 1),
-            "analysis_bytes": round(self.analysis_bytes, 1),
-            "achieved_bytes": round(self.achieved_bytes, 1),
-            "achieved_gbps": round(self.achieved_gbps, 4),
-            "requant": self.requant, "layers": self.layers,
-            "roofline_ms": self.roofline_ms,
-            "roofline_frac": self.roofline_frac,
-        }
-
-
-@dataclass
-class PlanProfile:
-    """Whole-plan profile: per-segment rows + aggregate timings."""
-    graph_name: str
-    batch: int
-    repeats: int
-    segments: list[SegmentProfile]
-    plan_ms: float                    # fused end-to-end jitted call
-    sum_segments_ms: float
-    bw_gbps: Optional[float] = None   # peak used for the roofline column
-
-    @property
-    def total_macs(self) -> int:
-        return sum(s.macs for s in self.segments)
-
-    @property
-    def macs_per_s(self) -> float:
-        return (self.total_macs * self.batch / (self.plan_ms / 1e3)
-                if self.plan_ms else 0.0)
-
-    def table(self) -> str:
-        head = (f"{'seg':>3s} {'kind':22s} {'ops':26s} {'ms':>8s} "
-                f"{'MACs':>12s} {'GMAC/s':>8s} {'KiB(min)':>9s} "
-                f"{'KiB(act)':>9s} {'GB/s':>7s} {'requant':>7s}")
-        if self.bw_gbps:
-            head += f" {'roofline':>8s}"
-        lines = [head, "-" * len(head)]
-        for s in self.segments:
-            line = (f"{s.index:3d} {s.kind[:22]:22s} {s.ops[:26]:26s} "
-                    f"{s.measured_ms:8.3f} {s.macs:12,d} "
-                    f"{s.macs_per_s / 1e9:8.3f} "
-                    f"{s.analysis_bytes / 1024:9.1f} "
-                    f"{s.achieved_bytes / 1024:9.1f} "
-                    f"{s.achieved_gbps:7.2f} {s.requant or '-':>7s}")
-            if self.bw_gbps:
-                line += (f" {s.roofline_frac:8.1%}"
-                         if s.roofline_frac is not None else f" {'-':>8s}")
-            lines.append(line)
-        lines.append("-" * len(head))
-        lines.append(
-            f"{self.graph_name}: plan {self.plan_ms:.3f} ms "
-            f"(batch {self.batch}, {self.macs_per_s / 1e9:.3f} GMAC/s), "
-            f"sum of segments {self.sum_segments_ms:.3f} ms "
-            f"({self.sum_segments_ms / self.plan_ms:.2f}x — the gap is "
-            f"cross-segment XLA fusion)" if self.plan_ms else
-            f"{self.graph_name}: empty plan")
-        return "\n".join(lines)
-
-    def to_json(self) -> dict:
-        return {
-            "model": self.graph_name, "batch": self.batch,
-            "repeats": self.repeats,
-            "plan_ms": round(self.plan_ms, 4),
-            "sum_segments_ms": round(self.sum_segments_ms, 4),
-            "total_macs": self.total_macs,
-            "macs_per_s": round(self.macs_per_s, 1),
-            "bw_gbps": self.bw_gbps,
-            "segments": [s.to_json() for s in self.segments],
-        }
-
-
-def _segment_fn(seg):
-    """Jittable (consts, env_in) -> outputs wrapper over ``seg.run``."""
-    def fn(consts, env_in):
-        env = dict(env_in)
-        seg.run(consts, env)
-        return {o: env[o] for o in seg.outputs if o in env}
-    return fn
-
-
-def _nbytes(v) -> int:
-    return int(np.prod(v.shape)) * jnp.dtype(v.dtype).itemsize if hasattr(
-        v, "shape") else 0
-
-
-# historical internal name; the implementation is the shared ``time_fn``
-_time_best = time_fn
-
-
-def profile_plan(plan, x=None, *, repeats: int = 20,
-                 cost_report=None, bw_gbps: Optional[float] = None,
-                 registry=None) -> PlanProfile:
-    """Profile every segment of ``plan`` (see module docstring).
-
-    x           — input array (graph's declared shape by default, seeded
-                  randn); a dict {input_name: array} is accepted too
-    repeats     — timing repeats per segment (best-of, after a warm call)
-    cost_report — a precomputed ``infer_cost`` report over ``plan.graph``
-                  (one is computed from ``plan.analysis`` otherwise)
-    bw_gbps     — optional peak memory bandwidth: adds the roofline column
-                  (analysis-minimal bytes / peak BW vs measured ms)
-    registry    — optional ``MetricsRegistry``: per-segment measured ms
-                  land in the ``profile_segment_ms`` gauge family
-    """
-    g = plan.graph
-    if isinstance(x, dict):
-        inputs = {k: jnp.asarray(v) for k, v in x.items()}
-    else:
-        if x is None:
-            shape = tuple(1 if d is None else int(d)
-                          for d in g.inputs[0].shape)
-            x = np.random.RandomState(0).randn(*shape).astype(np.float32)
-        inputs = {g.input_names[0]: jnp.asarray(x)}
-    batch = int(next(iter(inputs.values())).shape[0])
-
-    if cost_report is None:
-        from repro.analysis import infer_cost
-        cost_report = infer_cost(g, ga=plan.analysis)
-    layers_by_name = {l.name: l for l in cost_report.layers}
-
-    # fused end-to-end reference: the jitted plan, one trailing sync
-    out_names = list(g.output_names)
-    plan_s = _time_best(
-        lambda: [plan(inputs)[n] for n in out_names], repeats)
-
-    env = dict(inputs)
-    rows: list[SegmentProfile] = []
-    for idx, seg in enumerate(plan.segments):
-        fn = jax.jit(_segment_fn(seg))
-        env_in = {name: env[name] for name in seg.inputs if name in env}
-        out = fn(plan.consts, env_in)
-        seg_s = _time_best(lambda: fn(plan.consts, env_in), repeats)
-        joined = [n.name for n in seg.nodes if n.name in layers_by_name]
-        macs = sum(layers_by_name[n].macs for n in joined)
-        a_bytes = sum(layers_by_name[n].mem_bytes for n in joined) * batch
-        # bytes actually moved: activation inputs + outputs at their live
-        # dtypes, plus the staged consts (packed carriers, scales)
-        moved = sum(_nbytes(v) for v in env_in.values())
-        moved += sum(_nbytes(v) for v in out.values())
-        moved += sum(_nbytes(plan.consts[k]) for k in seg.const_keys
-                     if k in plan.consts)
-        if not a_bytes:
-            a_bytes = float(moved)     # no joined layer: actual is minimal
-        ms = seg_s * 1e3
-        row = SegmentProfile(
-            index=idx, kind=seg.kind,
-            ops="+".join(n.op_type for n in seg.nodes),
-            measured_ms=ms,
-            macs=int(macs),
-            macs_per_s=macs * batch / seg_s if seg_s else 0.0,
-            analysis_bytes=float(a_bytes),
-            achieved_bytes=float(moved),
-            achieved_gbps=moved / seg_s / 1e9 if seg_s else 0.0,
-            requant=seg.meta.get("requant_path"),
-            layers=joined)
-        if bw_gbps:
-            row.roofline_ms = row.analysis_bytes / (bw_gbps * 1e9) * 1e3
-            row.roofline_frac = (row.roofline_ms / ms) if ms else None
-        rows.append(row)
-        if registry is not None:
-            registry.gauge(
-                "profile_segment_ms", unit="ms",
-                help="per-segment measured wall time (profile mode)",
-                labels={"model": g.name, "segment": str(idx),
-                        "kind": seg.kind}).set(ms)
-        env.update(out)
-
-    return PlanProfile(
-        graph_name=g.name, batch=batch, repeats=repeats, segments=rows,
-        plan_ms=plan_s * 1e3,
-        sum_segments_ms=sum(r.measured_ms for r in rows),
-        bw_gbps=bw_gbps)

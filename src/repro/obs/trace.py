@@ -13,21 +13,30 @@ Two ways to produce spans:
             with tracer.span("dispatch", parent=sp):
                 ...
 
-  * retroactively, via ``emit(name, t0, t1, ...)`` — the serving engine
-    already timestamps every request (submit/dispatch/complete), so at
-    flush time it emits the submit->queue->dispatch->sync->complete spans
-    from those timestamps without adding clock reads to the hot path.
+    While the tracer is enabled, a live span also holds a
+    ``jax.profiler.TraceAnnotation`` of its name open on the thread that
+    entered it, so a profiler trace taken meanwhile has the span's host
+    interval on the same clock as the device's ops.  The serving engine's
+    steps (``call``/``flush`` and their ``stage``/``dispatch``/``sync``/
+    ``fetch`` children) are live spans.
+
+  * retroactively, via ``emit(name, t0, t1, ...)``, from timestamps taken
+    earlier.  Such a span never reaches the profiler: the engine uses it
+    only for the per-request ``request``/``queued``/``compute`` spans,
+    from the submit/dispatch/complete stamps every request carries.
 
 Disabled tracing is **free**: instrumented code guards on
 ``tracer is not None and tracer.enabled`` (the serving engine folds this
-into one attribute check), so the submit hot path performs zero
-allocations attributable to this module — proven by the tracemalloc test
-in tests/test_obs.py.  Sinks are locked; span records are plain dicts::
+into one attribute check), so the engine's ``submit`` and ``__call__``
+perform zero allocations attributable to this module — proven by the
+tracemalloc tests in tests/test_obs.py.  Sinks are locked; span records
+are plain dicts::
 
     {"name", "trace", "span", "parent", "t0", "t1", "dur_ms", ...attrs}
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import threading
@@ -45,12 +54,23 @@ def _new_id() -> str:
         return f"{next(_ids):08x}"
 
 
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    ``repro.obs`` imports without JAX; None where JAX is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class Span:
     """One named interval.  Ends (and reaches the sink) on ``end()`` or
     context-manager exit; attributes are set at creation or via ``set``."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "t1",
-                 "attrs", "_tracer")
+                 "attrs", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_id: Optional[str], t0: float, attrs: dict):
@@ -62,6 +82,7 @@ class Span:
         self.t0 = t0
         self.t1: Optional[float] = None
         self.attrs = attrs
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -74,9 +95,16 @@ class Span:
         self._tracer._record(self)
 
     def __enter__(self) -> "Span":
+        annotation = _trace_annotation() if self._tracer.enabled else None
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         self.end()
 
     def to_record(self) -> dict:

@@ -26,6 +26,7 @@ still-queued old-model requests through the old plan and swaps.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -43,6 +44,10 @@ log = logging.getLogger("repro.serve")
 # slot occupancy is a fraction of max_batch — linear buckets, not the
 # default exponential latency layout
 _OCCUPANCY_BUCKETS = tuple(i / 8 for i in range(1, 9))
+
+# what a step's ``with`` enters when tracing is off: one shared, stateless
+# context, so an untraced call allocates nothing for its spans
+_NO_SPAN = contextlib.nullcontext()
 
 
 def percentile_ms(values, pct: float) -> float:
@@ -167,9 +172,11 @@ class CompiledGraphEngine:
         # export a whole fleet from one ``--metrics-port`` endpoint.
         # ``observability=False`` keeps only the plain-int lifetime
         # counters — the pre-obs baseline the bench_serve overhead gate
-        # measures against.  ``tracer`` (repro.obs.Tracer) turns the
-        # request lifecycle into submit->queue->flush->dispatch->sync->
-        # complete spans; None/disabled adds zero work to the hot path.
+        # measures against.  ``tracer`` (repro.obs.Tracer) records each
+        # flush and each ``__call__`` as live spans of their steps
+        # (flush|call -> stage/dispatch/sync/fetch, on the profiler's
+        # clock too) and each request's request->queued/compute spans;
+        # None/disabled adds zero work to the hot path.
         self.metrics = metrics_registry or MetricsRegistry()
         self._metric_labels = dict(metrics_labels or
                                    {"model": getattr(graph, "name", "graph")})
@@ -426,31 +433,46 @@ class CompiledGraphEngine:
         return self._run_requests(reqs, state)
 
     def _run_requests(self, reqs: list, state: tuple) -> int:
-        plan, in_name, out_name, sample_shape = state
         tr = self._tracer
-        tracing = tr is not None and tr.enabled
-        t_flush0 = time.monotonic()
+        span = _NO_SPAN
+        if tr is not None and tr.enabled:
+            n_slots = -(-len(reqs) // self.max_batch)
+            span = tr.span("flush", n_requests=len(reqs), n_slots=n_slots,
+                           slot_occupancy=round(
+                               len(reqs) / (n_slots * self.max_batch), 4),
+                           pipeline=self.pipeline)
+        # one call site, traced or not: a Pallas kernel's compiled payload
+        # records its callers' source lines, so two sites would compile and
+        # cache the same program twice
+        with span as root:
+            return self._flush(reqs, state, tr, root)
+
+    def _flush(self, reqs: list, state: tuple, tr, root) -> int:
+        """``_run_requests``'s steps; with a root span (tracing on) each is
+        a live child span of it: ``dispatch``, ``sync``, ``fetch``."""
+        plan, in_name, out_name, sample_shape = state
+        tracing = root is not None
         dispatched = []
         try:
-            for i in range(0, len(reqs), self.max_batch):
-                batch = reqs[i:i + self.max_batch]
-                t_dispatch = time.monotonic()
-                for r in batch:
-                    r.started = t_dispatch
-                x = self._pad_to_slot(jnp.stack([r.x for r in batch]),
-                                      sample_shape, owned=True)
-                out = plan({in_name: x}, donate=self._donate)[out_name]
-                dispatched.append((batch, out))
-                if self._obs_on:
-                    self._m_occupancy.observe(len(batch) / self.max_batch)
-                if not self.pipeline:          # per-slot host sync: baseline
-                    jax.block_until_ready(out)
-            t_sync0 = time.monotonic()
-            if self.pipeline:                  # single trailing sync
-                jax.block_until_ready([o for _, o in dispatched])
-            if tracing:
-                self._emit_flush_spans(tr, reqs, len(dispatched),
-                                       t_flush0, t_sync0, time.monotonic())
+            with (tr.span("dispatch", parent=root,
+                          n_slots=root.attrs["n_slots"])
+                  if tracing else _NO_SPAN):
+                for i in range(0, len(reqs), self.max_batch):
+                    batch = reqs[i:i + self.max_batch]
+                    t_dispatch = time.monotonic()
+                    for r in batch:
+                        r.started = t_dispatch
+                    x = self._pad_to_slot(jnp.stack([r.x for r in batch]),
+                                          sample_shape, owned=True)
+                    out = plan({in_name: x}, donate=self._donate)[out_name]
+                    dispatched.append((batch, out))
+                    if self._obs_on:
+                        self._m_occupancy.observe(len(batch) / self.max_batch)
+                    if not self.pipeline:      # per-slot host sync: baseline
+                        jax.block_until_ready(out)
+            with tr.span("sync", parent=root) if tracing else _NO_SPAN:
+                if self.pipeline:              # single trailing sync
+                    jax.block_until_ready([o for _, o in dispatched])
         except Exception as e:
             # scope the failure: every dispatched slot whose compute
             # actually succeeded still completes (the scatter forces it) and
@@ -469,8 +491,9 @@ class CompiledGraphEngine:
             if completed:
                 self._record(completed)
             raise
-        for batch, out in dispatched:
-            self._scatter(batch, out)
+        with tr.span("fetch", parent=root) if tracing else _NO_SPAN:
+            for batch, out in dispatched:
+                self._scatter(batch, out)
         self._record(reqs)
         return len(reqs)
 
@@ -481,23 +504,6 @@ class CompiledGraphEngine:
             # copy the row out of the slot so a held future pins one row,
             # not the whole padded (max_batch, ...) output buffer
             r._finish(rows[j].copy())
-
-    def _emit_flush_spans(self, tr, reqs: list, n_slots: int,
-                          t_flush0: float, t_sync0: float,
-                          t_end: float) -> None:
-        """One flush trace: flush -> dispatch + sync children (monotonic
-        timestamps, shared with the per-request spans in ``_record`` and
-        with the tracer's own live-span clock)."""
-        trace_id = tr.new_trace_id()
-        occupancy = len(reqs) / max(1, n_slots * self.max_batch)
-        flush_id = tr.emit(
-            "flush", t_flush0, t_end, trace_id=trace_id,
-            n_requests=len(reqs), n_slots=n_slots,
-            slot_occupancy=round(occupancy, 4), pipeline=self.pipeline)
-        tr.emit("dispatch", t_flush0, t_sync0, trace_id=trace_id,
-                parent_id=flush_id, n_slots=n_slots)
-        tr.emit("sync", t_sync0, t_end, trace_id=trace_id,
-                parent_id=flush_id)
 
     def _record(self, reqs: list) -> None:
         n_miss = 0
@@ -594,8 +600,24 @@ class CompiledGraphEngine:
         single trailing sync — chunk *k+1*'s dispatch overlaps chunk *k*'s
         compute; ``pipeline=False`` forces each chunk to host before
         dispatching the next (the measured baseline).
+
+        With tracing on, the call is a ``call`` span (images, slots) whose
+        children are its steps: ``stage`` (the batch to the device, with
+        its bytes), ``dispatch`` (slice, pad and enqueue every slot),
+        ``sync`` (the trailing ``block_until_ready``) and ``fetch`` (every
+        slot to the host and the concatenate, with the result's bytes).
         """
-        x = jnp.asarray(x, jnp.float32)
+        tr = self._tracer
+        span = tr.span("call") if tr is not None and tr.enabled else _NO_SPAN
+        with span as root:        # one call site, as in ``_run_requests``
+            return self._call(x, tr, root)
+
+    def _call(self, x, tr, root) -> np.ndarray:
+        tracing = root is not None
+        with tr.span("stage", parent=root) if tracing else _NO_SPAN as sp:
+            x = jnp.asarray(x, jnp.float32)
+            if tracing:
+                sp.set(bytes=x.nbytes)
         with self._lock:
             plan, in_name, out_name, sample_shape = self._serving_state()
         unbatched = x.shape == sample_shape
@@ -622,15 +644,23 @@ class CompiledGraphEngine:
                     if self.plan is plan:
                         self._out_spec = spec
             return np.zeros((0,) + tuple(spec.shape[1:]), spec.dtype)
+        if tracing:
+            root.set(images=x.shape[0],
+                     slots=-(-x.shape[0] // self.max_batch))
         outs = []
-        for i in range(0, x.shape[0], self.max_batch):
-            chunk = x[i:i + self.max_batch]
-            out = plan({in_name: self._pad_to_slot(chunk, sample_shape)},
-                       donate=self._donate)[out_name]
-            outs.append(out[:chunk.shape[0]])   # lazy slice, stays on device
-            if not self.pipeline:
-                jax.block_until_ready(out)      # per-chunk stall: baseline
-        if self.pipeline:
-            jax.block_until_ready(outs)         # one sync for all chunks
-        result = np.concatenate([np.asarray(o) for o in outs], axis=0)
+        with tr.span("dispatch", parent=root) if tracing else _NO_SPAN:
+            for i in range(0, x.shape[0], self.max_batch):
+                chunk = x[i:i + self.max_batch]
+                out = plan({in_name: self._pad_to_slot(chunk, sample_shape)},
+                           donate=self._donate)[out_name]
+                outs.append(out[:chunk.shape[0]])  # lazy slice, on device
+                if not self.pipeline:
+                    jax.block_until_ready(out)     # per-chunk stall: baseline
+        with tr.span("sync", parent=root) if tracing else _NO_SPAN:
+            if self.pipeline:
+                jax.block_until_ready(outs)        # one sync for all chunks
+        with tr.span("fetch", parent=root) if tracing else _NO_SPAN as sp:
+            result = np.concatenate([np.asarray(o) for o in outs], axis=0)
+            if tracing:
+                sp.set(bytes=result.nbytes)
         return result[0] if unbatched else result

@@ -4,11 +4,12 @@ Covers repro.obs (registry/counter/gauge/histogram semantics, exporters,
 span parent/child links, the JSONL sink, the report CLI, the HTTP
 endpoint) and its integration into the compile and serve tiers — the
 ``*_total`` stats keys, the shared-registry fleet export, the
-disabled-tracer zero-allocation guarantee on the submit hot path, and the
-per-segment profiler joined with the analysis cost report on the zoo
-conv models.
+disabled-tracer zero-allocation guarantee on the submit and call paths,
+and the engine's live spans read back from a JAX profiler trace.
 """
+import glob
 import json
+import os
 import threading
 import tracemalloc
 import urllib.request
@@ -361,24 +362,103 @@ def test_engine_emits_request_and_flush_spans():
         assert {k["name"] for k in kids} == {"queued", "compute"}
 
 
-def test_disabled_tracer_adds_zero_allocations_to_submit():
+def _tracer_allocations(eng, step, n=50) -> int:
+    """Bytes allocated in repro.obs.trace over ``n`` calls of ``step``."""
     import repro.obs.trace as trace_mod
-    eng = _engine(tracer=Tracer(ListSink(), enabled=False))
-    x = np.zeros(16, np.float32)
-    for _ in range(4):                       # warm every lazy path
-        eng.submit(x)
-    eng.run_pending()
     tracemalloc.start()
     try:
-        for _ in range(50):
-            eng.submit(x)
+        for _ in range(n):
+            step()
         snap = tracemalloc.take_snapshot()
     finally:
         tracemalloc.stop()
     eng.run_pending()
     trace_allocs = snap.filter_traces(
         [tracemalloc.Filter(True, trace_mod.__file__)]).statistics("lineno")
-    assert sum(s.size for s in trace_allocs) == 0
+    return sum(s.size for s in trace_allocs)
+
+
+def test_disabled_tracer_adds_zero_allocations_to_submit():
+    eng = _engine(tracer=Tracer(ListSink(), enabled=False))
+    x = np.zeros(16, np.float32)
+    for _ in range(4):                       # warm every lazy path
+        eng.submit(x)
+    eng.run_pending()
+    assert _tracer_allocations(eng, lambda: eng.submit(x)) == 0
+
+
+@pytest.mark.parametrize("tracer", ["none", "disabled"])
+def test_disabled_tracer_adds_zero_allocations_to_call(tracer):
+    """``__call__`` with ``tracer=None`` or a disabled tracer takes no
+    span path: its steps enter the shared no-op context."""
+    eng = _engine(tracer=None if tracer == "none" else
+                  Tracer(ListSink(), enabled=False))
+    x = np.zeros((6, 16), np.float32)
+    eng(x)                                   # warm every lazy path
+    assert _tracer_allocations(eng, lambda: eng(x), n=20) == 0
+
+
+# ------------------------------------------- spans on the profiler's clock
+
+ENGINE_STEPS = ("call", "flush", "stage", "dispatch", "sync", "fetch")
+
+
+@pytest.fixture(scope="module")
+def profiled_engine(tmp_path_factory):
+    """One ``__call__`` (two slots) and one ``run_pending`` flush of a
+    traced engine under ``jax.profiler.trace``; the engine's live spans
+    read back from the host plane of the recorded ``.xplane.pb``."""
+    import jax
+    from jax.profiler import ProfileData
+    sink = ListSink()
+    eng = _engine(tracer=Tracer(sink))
+    x = np.random.RandomState(5).randn(6, 16).astype(np.float32)
+    eng(x)                                   # compile outside the trace
+    sink.clear()
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+    with jax.profiler.trace(trace_dir):
+        eng(x)
+        for row in x[:5]:
+            eng.submit(row)
+        eng.run_pending()
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                events += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                           for e in line.events
+                           if e.name in ENGINE_STEPS + ("request",)]
+    return sink, sorted(events, key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("root", ["call", "flush"])
+def test_engine_steps_nest_on_the_profiler_trace(profiled_engine, root):
+    sink, events = profiled_engine
+    (top,) = [e for e in events if e[0] == root]
+    kids = [e for e in events if top[1] <= e[1] and e[2] <= top[2]
+            and e is not top]
+    steps = ("stage", "dispatch", "sync", "fetch") if root == "call" \
+        else ("dispatch", "sync", "fetch")
+    assert [e[0] for e in kids] == list(steps)      # in order, each once
+    assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+    # the sink's records are the same spans, linked to the root
+    (rec,) = [r for r in sink if r["name"] == root]
+    assert sorted(r["name"] for r in sink
+                  if r["parent"] == rec["span"]) == sorted(steps)
+    if root == "call":
+        assert rec["images"] == 6 and rec["slots"] == 2
+        stage, fetch = (next(r for r in sink if r["name"] == n
+                             and r["parent"] == rec["span"])
+                        for n in ("stage", "fetch"))
+        assert stage["bytes"] == 6 * 16 * 4 and fetch["bytes"] == 6 * 6 * 4
+
+
+def test_retroactive_spans_stay_off_the_profiler_trace(profiled_engine):
+    sink, events = profiled_engine
+    assert sum(r["name"] == "request" for r in sink) == 5
+    assert not [e for e in events if e[0] == "request"]
 
 
 def test_scheduler_stats_totals_and_rejection_counter():
@@ -433,68 +513,3 @@ def test_compile_records_wall_time_and_plan_gauges():
     plan({"x": np.zeros((1, 16), np.float32)})      # same shape: no retrace
     assert plan.trace_count == t0 + 1
     assert retrace.value == r0 + 1
-
-
-# ----------------------------------------------------- segment profiler
-
-def _check_profile(prof, plan):
-    assert len(prof.segments) == len(plan.segments)
-    mac_total = 0
-    for row, seg in zip(prof.segments, plan.segments):
-        assert row.kind == seg.kind
-        assert row.measured_ms > 0
-        assert row.achieved_bytes > 0 and row.analysis_bytes > 0
-        assert row.requant == seg.meta.get("requant_path")
-        if row.macs:
-            assert row.macs_per_s > 0 and row.layers
-        mac_total += row.macs
-    # the cost-report join accounts for every MAC in the model
-    from repro.analysis import infer_cost
-    assert mac_total == infer_cost(plan.graph, ga=plan.analysis).macs
-    assert prof.plan_ms > 0
-    assert prof.sum_segments_ms == pytest.approx(
-        sum(r.measured_ms for r in prof.segments))
-    # the table renders one line per segment plus header/footer
-    table = prof.table()
-    assert len(table.splitlines()) == len(prof.segments) + 4
-    js = prof.to_json()
-    assert js["total_macs"] == mac_total
-    assert len(js["segments"]) == len(prof.segments)
-
-
-def test_profile_joins_cost_report_on_conv_models():
-    from repro.core.compile import compile_graph
-    from repro.models import zoo
-    plan = compile_graph(zoo.ZOO["CNV-w1a1"]())
-    prof = plan.profile(repeats=1, bw_gbps=819.0)
-    _check_profile(prof, plan)
-    # every fused kernel segment reports a measured MAC rate
-    kernel_rows = [r for r in prof.segments
-                   if r.kind.startswith(("quant_conv", "quant_matmul"))]
-    assert kernel_rows and all(r.macs > 0 and r.macs_per_s > 0
-                               for r in kernel_rows)
-    assert all(r.requant == "int32" for r in kernel_rows)
-    assert all(r.roofline_ms is not None for r in prof.segments)
-
-
-def test_profile_mobilenet_grouped_segments():
-    from repro.core.compile import compile_graph
-    from repro.models.zoo import build_mobilenet
-    plan = compile_graph(build_mobilenet(4, 4, img=32))
-    prof = plan.profile(repeats=1)
-    _check_profile(prof, plan)
-    grouped = [r for r in prof.segments
-               if r.kind.startswith(("quant_conv_grouped", "quant_conv_dw"))]
-    assert grouped and all(r.measured_ms > 0 and r.macs > 0 for r in grouped)
-
-
-def test_profile_registry_gauges_and_batch_input():
-    from repro.core.compile import compile_graph
-    reg = MetricsRegistry()
-    plan = compile_graph(_mlp(seed=5))
-    x = np.random.RandomState(0).randn(4, 16).astype(np.float32)
-    prof = plan.profile({"x": x}, repeats=1, registry=reg)
-    assert prof.batch == 4
-    gauges = reg.snapshot()["profile_segment_ms"]["series"]
-    assert len(gauges) == len(plan.segments)
-    assert all(s["value"] > 0 for s in gauges)
