@@ -48,6 +48,7 @@ the model zoo in all three formats.
 from __future__ import annotations
 
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -260,6 +261,13 @@ class CompiledPlan:
         for s in self.segments:
             out[s.kind] = out.get(s.kind, 0) + 1
         return out
+
+    @property
+    def patch_layouts(self) -> dict:
+        """Patch build (``meta["patch_layout"]``) -> number of conv
+        segments that unfold through it; depthwise taps have none."""
+        return dict(Counter(s.meta["patch_layout"] for s in self.segments
+                            if "patch_layout" in s.meta))
 
     @property
     def n_fused_nodes(self) -> int:

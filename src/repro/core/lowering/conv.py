@@ -12,13 +12,15 @@ interpreted fallback.
 How it lowers (FINN-R / TVM-quantization style):
 
   * the integer conv weights (O, I/g, kH, kW) are reshaped **at compile
-    time** into a (C·kH·kW, O) matmul operand
-    (``kernels.im2col_weights``) — block-diagonal for grouped/depthwise
-    convs, so the MXU kernels see one dense int8/int4 carrier;
-  * at trace time the activation is unfolded into im2col patches and fed
-    through ``kernels.quant_conv2d`` -> ``quant_matmul[_int4]``; stride,
-    padding, dilation and 1x1-pointwise all reduce to how the patches are
-    sliced;
+    time** into a (kH·kW·C, O) matmul operand, rows ordered (kh, kw, c)
+    with the channel fastest (``kernels.im2col_weights``) — block-diagonal
+    over the groups within each tap for grouped/depthwise convs, so the
+    MXU kernels see one dense int8/int4 carrier;
+  * at trace time the activation is unfolded into im2col patches in the
+    same feature order and fed through ``kernels.quant_conv2d`` ->
+    ``quant_matmul[_int4]``; stride, padding, dilation and 1x1-pointwise
+    all reduce to how the patches are sliced, and the segment's
+    ``meta["patch_layout"]`` names the build (``kernels.patch_layout``);
   * a trailing Relu fuses as a max(0, ·) epilogue, and a trailing
     per-tensor activation Quant fuses as a ``quant_dequant`` kernel call on
     the still-2D matmul output — the common Conv->Relu->Quant block of the
@@ -195,7 +197,7 @@ class QuantConvRule(LoweringRule):
         nb = match_conv_common(g, node, ctx)
         if nb is None:
             return None
-        w2 = im2col_weights(nb.qw.w_int, nb.group)     # (C·kH·kW, O) int8
+        w2 = im2col_weights(nb.qw.w_int, nb.group)     # (kH·kW·C, O) int8
         int4_ok = nb.qw.int4_values and w2.shape[0] % 2 == 0
 
         m = QuantConvMatch(
@@ -281,6 +283,7 @@ class QuantConvRule(LoweringRule):
                 y = fusion.boundary_out(y, cout)
             env[out_name] = y
 
+        meta["patch_layout"] = kernel_ops.patch_layout(m.kernel_shape, m.pads)
         if m.group > 1:
             meta["group"] = m.group
         if cin is not None or cout is not None:

@@ -244,6 +244,8 @@ class GroupedConvRule(LoweringRule):
                     y = fusion.boundary_out(y, cout)
                 env[out_name] = y
 
+            meta["patch_layout"] = kernel_ops.patch_layout(m.kernel_shape,
+                                                           m.pads)
         meta["group"] = m.group
         meta["reclaimed_macs"] = m.reclaimed_macs
         # bytes = dense fallback's carrier (C·kH·kW·O entries at *its* int4

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 
 from ._blocks import default_interpret, resolve_interpret  # noqa: F401
 from .quant_conv import (  # noqa: F401  (public re-exports)
-    extract_patches, im2col_weights, quant_conv2d)
+    extract_patches, im2col_weights, patch_layout, patch_rows_to_nchw,
+    quant_conv2d)
 from .quant_dequant import quant_dequant  # noqa: F401
 from .quant_grouped_conv import (  # noqa: F401
     depthwise_weights, extract_depthwise_taps, grouped_weights,

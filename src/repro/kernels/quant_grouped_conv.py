@@ -46,7 +46,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._blocks import (resolve_interpret as _resolve_interpret,
                       round_up as _round_up)
-from .quant_conv import conv_tap_slices, extract_patches, mxu_operand
+from .quant_conv import (conv_tap_slices, extract_patches, mxu_operand,
+                         patch_layout, patch_rows_to_nchw)
 from .quant_dequant import _round_kernel_body, _static_bounds
 from .quant_matmul import DEFAULT_BLOCKS, _unpack_lo_hi, mxu_dot
 from .ref import pack_int4_ref, unpack_int4_ref
@@ -62,16 +63,17 @@ def grouped_weights(w, groups: int) -> np.ndarray:
 
     Group ``gi``'s slice ``[gi]`` is the ``(I/g·kH·kW, O/g)`` matmul operand
     of that group alone — the block-diagonal zeros of ``im2col_weights``
-    never exist.  Row order within a group is (c, kh, kw) with the channel
-    varying slowest, matching ``extract_patches``'s feature axis.
+    never exist.  Row order within a group is (kh, kw, c) with the channel
+    varying fastest, matching ``extract_patches``'s feature axis.
     """
     w = np.asarray(w)
     o, ipg, kh, kw = w.shape
     if o % groups:
         raise ValueError(f"output channels {o} not divisible by groups {groups}")
     opg = o // groups
-    wm = w.reshape(groups, opg, ipg * kh * kw)
-    return np.ascontiguousarray(np.transpose(wm, (0, 2, 1)))
+    wm = w.reshape(groups, opg, ipg, kh, kw)
+    return np.ascontiguousarray(np.transpose(wm, (0, 3, 4, 2, 1))
+                                .reshape(groups, kh * kw * ipg, opg))
 
 
 def depthwise_weights(w) -> np.ndarray:
@@ -111,8 +113,8 @@ def extract_depthwise_taps(x, kernel_shape, strides=(1, 1), pads=(0, 0, 0, 0),
     """Unfold NCHW ``x`` into per-tap channel-minor slices.
 
     Returns ``(taps, (OH, OW))`` where taps has shape (kH·kW, N·OH·OW, C):
-    the same strided slices ``extract_patches`` takes
-    (``quant_conv.conv_tap_slices`` is the shared unfold geometry), but the
+    the same taps ``extract_patches`` takes, sliced from NCHW
+    (``quant_conv.conv_tap_slices``), but the
     channel axis stays whole (moved to the minor/lane position) instead of
     being folded into a dense feature axis — depthwise never mixes
     channels, so there is nothing to contract across.  Under the
@@ -243,7 +245,7 @@ def quant_grouped_conv2d(x, wg, w_scale, bias=None, *, groups, kernel_shape,
     x        — (N, C, H, W) activations (cast to f32; int8 codes pass
                through to the MXU's int8 path)
     wg       — per-group integer weights (G, Kg, Ng) int8 with
-               Kg = (C/G)·kH·kW and Ng = O/G, or the per-group int4 packing
+               Kg = kH·kW·(C/G) and Ng = O/G, or the per-group int4 packing
                (G, Kg//2, Ng) when ``packed`` (``grouped_weights`` /
                ``pack_int4_grouped``)
     w_scale  — dequant scale, scalar or group-major per-output-channel (O,)
@@ -256,11 +258,14 @@ def quant_grouped_conv2d(x, wg, w_scale, bias=None, *, groups, kernel_shape,
     patches, (oh, ow) = extract_patches(x, kernel_shape, strides, pads,
                                         dilations)
     m, feat = patches.shape
-    kg = feat // groups
-    # channel is the slowest feature axis, so group gi's columns are the
-    # contiguous slice [gi·Kg, (gi+1)·Kg): one reshape, no gather
+    c = x.shape[1]
+    taps, cg = feat // c, c // groups
+    # features are (tap, channel) with the channel fastest, so group gi's
+    # columns are the channels [gi·Cg, (gi+1)·Cg) of every tap: one
+    # reshape and one transpose, no gather
     with jax.named_scope("im2col"):
-        xg = jnp.transpose(patches.reshape(m, groups, kg), (1, 0, 2))
+        xg = jnp.transpose(patches.reshape(m, taps, groups, cg),
+                           (2, 0, 1, 3)).reshape(groups, m, taps * cg)
     y = quant_grouped_matmul(xg, wg, w_scale, packed=packed, blocks=blocks,
                              interpret=interpret, out_dtype=out_dtype,
                              acc_dtype=acc_dtype,
@@ -269,8 +274,8 @@ def quant_grouped_conv2d(x, wg, w_scale, bias=None, *, groups, kernel_shape,
     y = jnp.transpose(y, (1, 0, 2)).reshape(m, o)
     if bias is not None:
         y = y + bias.astype(y.dtype)
-    y = y.reshape(x.shape[0], oh, ow, o)
-    return jnp.transpose(y, (0, 3, 1, 2))
+    return patch_rows_to_nchw(y, x.shape[0], oh, ow,
+                              patch_layout(kernel_shape, pads))
 
 
 # ---------------------------------------------- depthwise VPU tap-reduce

@@ -9,6 +9,7 @@ collects the same tests and only the worker given this file loads the TPU
 library; where it cannot be described, every test here skips.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,3 +140,57 @@ def test_cnv_w1a1_plan_body_compiles(one_chip):
                                           "quant_dequant")))
     assert lowered.as_text().count("tpu_custom_call") == n_kernels
     lowered.compile()
+
+
+def _lane_sizes(line):
+    """Sizes of the lane (most minor, non-degenerate) axis of every array
+    an HLO instruction line yields, read from its layouts."""
+    out = []
+    head = line.split(" = ", 1)[1].split("metadata=")[0]
+    for dims, layout in re.findall(r"\w+\[([\d,]*)\]\{([\d,]*)", head):
+        dims = [int(d) for d in dims.split(",") if d]
+        minor = [dims[a] for a in map(int, filter(None, layout.split(",")))
+                 if dims[a] > 1]
+        if minor:
+            out.append(minor[0])
+    return out
+
+
+def test_cnv_w1a1_patches_keep_channels_on_the_lanes(one_chip):
+    """The benchmark's CNV-w1a1 plan at its offline slot (256): every conv
+    unfolds through the tap-major build, and no instruction the compiler
+    emits for an ``im2col`` scope lays kH·kW or OW along its lanes.  (The
+    zoo's CNV answers constant logits, so its convs compile away.)"""
+    from bench import layers as L, spec
+    from bench.graph import build_graph
+    from repro.core.compile import compile_graph
+    slot = 256
+    cfg = spec.Bench().config("cnv-w1a1")
+    layers = spec.Bench().layers(cfg)
+    graph = build_graph("cnv-w1a1", layers, L.draw_weights(layers, 3),
+                        cfg["input_shape"])
+    plan = compile_graph(graph, interpret=False)
+    assert plan.patch_layouts == {"tap_major": 6}
+    banned = {}
+    for i, seg in enumerate(plan.segments):
+        for node in seg.nodes:
+            if node.op_type == "Conv":
+                kh, kw = node.attrs["kernel_shape"]
+                ow = plan.graph.get_shape(node.outputs[0])[3]
+                banned[f"seg{i:02d}_{seg.kind}"] = {kh * kw, ow}
+    assert len(banned) == 6
+    consts = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        plan.consts)
+    inputs = {plan.graph.input_names[0]: jax.ShapeDtypeStruct(
+        (slot, 3, 32, 32), jnp.float32, sharding=one_chip)}
+    text = jax.jit(plan._plan).lower(consts, inputs).compile().as_text()
+    seen = set()
+    for line in text.splitlines():
+        m = re.search(r'op_name="[^"]*/(seg\d\d_\w+)/im2col/', line)
+        if m is None or " = " not in line:
+            continue
+        seen.add(m.group(1))
+        lanes = _lane_sizes(line)
+        assert lanes and not banned[m.group(1)] & set(lanes), line.strip()
+    assert seen == set(banned)

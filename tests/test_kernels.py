@@ -202,7 +202,8 @@ def _conv_ref(x, w, strides, pads, dilations, groups):
     pad_pairs = [(pads[0], pads[2]), (pads[1], pads[3])]
     return jax.lax.conv_general_dilated(
         x, w, strides, pad_pairs, rhs_dilation=dilations,
-        dimension_numbers=dn, feature_group_count=groups)
+        dimension_numbers=dn, feature_group_count=groups,
+        precision=jax.lax.Precision.HIGHEST)
 
 
 @pytest.mark.parametrize("cin,cout,img,k,stride,pads,dil,groups", [
@@ -237,6 +238,59 @@ def test_quant_conv2d_matches_lax_conv(cin, cout, img, k, stride, pads, dil,
                                rtol=2e-5, atol=2e-4)
 
 
+_PATCH_GEOMETRIES = [  # (stride, pad, dilation, groups)
+    (1, 0, 1, 1), (2, 1, 1, 1), (1, 1, 2, 1), (1, 1, 1, 2), (2, 0, 2, 2),
+    (3, 1, 1, 1)]   # the last: rows past every tap's reach are cut off
+
+
+@pytest.mark.parametrize("stride,pad,dil,groups", _PATCH_GEOMETRIES,
+                         ids=["plain", "s2_p1", "dil2", "g2", "s2_dil2_g2",
+                              "s3_p1"])
+@pytest.mark.parametrize("k", [1, 3, 5])
+@pytest.mark.parametrize("cin", [3, 64, 128])
+@pytest.mark.parametrize("dtype", ["int8", "f32"])
+def test_tap_major_patches_times_weights_match_lax_conv(dtype, cin, k, stride,
+                                                        pad, dil, groups):
+    """``extract_patches`` and ``im2col_weights`` agree on the (kh, kw, c)
+    feature order: their product is the conv at ``HIGHEST``, exactly for
+    int8 codes (every sum fits f32's integers)."""
+    if cin % groups:
+        groups = 3                                 # C = 3: its own groups
+    rng = np.random.RandomState(cin + k)
+    cout = 2 * groups
+    w_int = rng.randint(-8, 8, size=(cout, cin // groups, k, k)) \
+        .astype(np.int8)
+    if dtype == "int8":
+        x = jnp.asarray(rng.randint(-128, 128, size=(2, cin, 9, 9))
+                        .astype(np.int8))
+    else:
+        x = jnp.asarray(rng.randn(2, cin, 9, 9).astype(np.float32))
+    pads = (pad,) * 4
+    patches, (oh, ow) = ops.extract_patches(x, (k, k), (stride, stride),
+                                            pads, (dil, dil))
+    w2 = ops.im2col_weights(w_int, groups)
+    assert patches.dtype == x.dtype
+    assert patches.shape == (2 * oh * ow, k * k * cin) == (2 * oh * ow,
+                                                           w2.shape[0])
+    if dtype == "int8":
+        y = jnp.dot(patches, jnp.asarray(w2),
+                    preferred_element_type=jnp.int32)
+    else:
+        y = jnp.dot(patches, jnp.asarray(w2, jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+    got = ops.patch_rows_to_nchw(y, 2, oh, ow,
+                                 ops.patch_layout((k, k), pads))
+    want = _conv_ref(x.astype(jnp.float32), jnp.asarray(w_int, jnp.float32),
+                     (stride, stride), pads, (dil, dil), groups)
+    assert got.shape == want.shape
+    if dtype == "int8":
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want).astype(np.int32))
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-4)
+
+
 def test_quant_conv2d_int4_packed_path_matches_int8():
     rng = np.random.RandomState(8)
     w_int = rng.randint(-8, 8, size=(6, 4, 3, 3)).astype(np.int8)
@@ -264,18 +318,23 @@ def test_quant_conv2d_bias():
 
 
 def test_im2col_weights_block_diagonal_structure():
-    """Grouped weights: off-block entries are exactly zero and each group's
-    block is the plain im2col of its slice."""
+    """Grouped weights: rows are (kh, kw, c) with the channel fastest; within
+    every tap the off-block entries are exactly zero and group gi's block
+    is that tap's slice of its own weights."""
     rng = np.random.RandomState(10)
     w = rng.randint(-8, 8, size=(4, 2, 3, 3)).astype(np.int8)   # groups=2
     w2 = ops.im2col_weights(w, groups=2)
     assert w2.shape == (4 * 9, 4)                  # cin=4 -> 36 rows
-    kg, opg = 2 * 9, 2
-    for gi in range(2):
-        block = w2[gi * kg:(gi + 1) * kg, gi * opg:(gi + 1) * opg]
-        np.testing.assert_array_equal(
-            block, w[gi * opg:(gi + 1) * opg].reshape(opg, -1).T)
-    w2[9 * 2:, :2] = 1                              # scribble on a block
+    ipg, opg = 2, 2
+    taps = w2.reshape(9, 4, 4)                     # (kH·kW, C, O)
+    for t in range(9):
+        for gi in range(2):
+            rows = slice(gi * ipg, (gi + 1) * ipg)
+            cols = slice(gi * opg, (gi + 1) * opg)
+            np.testing.assert_array_equal(
+                taps[t, rows, cols], w[cols, :, t // 3, t % 3].T)
+        assert np.all(taps[t, :ipg, opg:] == 0)
+        assert np.all(taps[t, ipg:, :opg] == 0)
+    w2[2:4, :2] = 1                                 # scribble on a block
     w2 = ops.im2col_weights(w, groups=2)            # rebuild
-    off = w2[kg:, :opg]
-    assert np.all(off == 0) and np.all(w2[:kg, opg:] == 0)
+    assert np.all(w2.reshape(9, 4, 4)[:, ipg:, :opg] == 0)
