@@ -2,7 +2,10 @@
 
 Traffic keys: ``max_batch`` (the engine's slot), ``call_batch`` (images per
 ``CompiledGraphEngine.__call__``), ``pool_calls`` (distinct input batches
-drawn from the seed, sent in turn).
+drawn from the seed, sent in turn) and, optionally, ``mesh``
+(``CompiledGraphEngine(mesh=...)``: ``"auto"`` runs one data-parallel plan
+that splits each slot over every local chip, the weights replicated;
+without it the plan runs on one device).
 
 The caller hands the engine host numpy batches and waits for each result,
 as a scoring job does.  ``images_per_s`` is every image returned inside
@@ -26,7 +29,7 @@ class Driver:
         t0 = time.monotonic()
         self.engine = CompiledGraphEngine(
             graph, max_batch=int(traffic["max_batch"]), report_cost=False,
-            tracer=tracer)
+            tracer=tracer, mesh=traffic.get("mesh"))
         t1 = time.monotonic()
         self.pool = [inputs.images(seed, 1 + i, self.call, input_shape)
                      for i in range(int(traffic["pool_calls"]))]

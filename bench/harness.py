@@ -97,16 +97,24 @@ def memory_peak_bytes() -> int:
 
 
 def compiled_hlo(plan, slot_rows: int, sample_shape) -> str:
-    """Text of the per-device program the window ran, compiled again
-    after the window (the persistent cache usually has it), for the
-    Pallas calls' shapes.  The plan offers no public handle on its
-    executable, so this lowers its jitted body."""
+    """Text of the per-device program the window ran on a slot of
+    ``slot_rows``, compiled again after the window (the persistent cache
+    usually has it), for the Pallas calls' shapes.  A mesh plan's program
+    is its ``shard_map`` over the slot split as its calls split it, so
+    the text holds each device's rows.  The plan offers no public handle
+    on its executable, so this lowers its jitted body."""
     import jax
     import numpy as np
-    shape = (slot_rows,) + tuple(sample_shape)
-    spec = {plan.graph.input_names[0]: jax.ShapeDtypeStruct(shape,
-                                                            np.float32)}
-    return plan._jitted.lower(plan.consts, spec).compile().as_text()
+    from jax.sharding import NamedSharding
+    from repro.dist import sharding as dsh
+    x = jax.ShapeDtypeStruct((slot_rows,) + tuple(sample_shape), np.float32)
+    fn = plan._jitted
+    if plan.n_devices > 1:            # as ``CompiledPlan._call_sharded``
+        fn = plan._jitted_spmd
+        x = jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(
+            plan.mesh, dsh.batch_pspecs(x, plan.mesh)))
+    return fn.lower(plan.consts,
+                    {plan.graph.input_names[0]: x}).compile().as_text()
 
 
 def run(args, *, require_tpu: bool = True, t_start: float | None = None,
@@ -176,8 +184,7 @@ def run(args, *, require_tpu: bool = True, t_start: float | None = None,
     if args.trace:
         t0 = time.monotonic()
         eng = drv.engine
-        hlo = compiled_hlo(eng.plan, eng.max_batch // eng.plan.n_devices,
-                           eng.sample_shape)
+        hlo = compiled_hlo(eng.plan, eng.max_batch, eng.sample_shape)
         say(f"compiled HLO read in {time.monotonic() - t0:.2f} s")
 
     # the program's answers, then its state freed before the reference

@@ -1,10 +1,10 @@
 """Plain fp32 ``jax.numpy`` forward of the QONNX semantics.
 
-It runs the generic layer list of ``bench/layers.py`` with nothing of the
-program: ``Quant`` is clip(round_half_even(x / s), lo, hi) * s (zero point
-0), ``BipolarQuant`` is s * sign (with sign(0) = +1), then ``Conv``,
-``MatMul``, ``Relu``, ``MaxPool``, ``GlobalAveragePool`` and ``Flatten``.
-Every contraction runs at ``Precision.HIGHEST``: on a TPU an fp32 matmul
+It walks the generic layer list of ``bench/layers.py`` with nothing of
+the program, each op computed by its file's ``reference`` (``bench/ops/``)
+and then the layer's ``act``, from the helpers here: ``Quant`` is clip(round_half_even(x / s), lo, hi)
+* s (zero point 0), ``BipolarQuant`` is s * sign (with sign(0) = +1), and
+every contraction runs at ``Precision.HIGHEST``: on a TPU an fp32 matmul
 otherwise runs in bf16 passes.
 
 ``control`` names a lower-precision copy that the ``correct`` comparison
@@ -59,15 +59,24 @@ def bipolar(x, scale_log2: int):
     return jnp.float32(2.0 ** scale_log2) * jnp.where(x >= 0, 1.0, -1.0)
 
 
-def _act(h, act, control):
-    if act is None:
+def _act(h, params, control):
+    """A layer's ``act``: optional ReLU, then its quantizer."""
+    if params is None:
         return h
-    if act["relu"]:
+    if params["relu"]:
         h = jnp.maximum(h, 0.0)
-    if act["bits"] == 1:
-        return bipolar(h, act["scale_log2"])
-    return quant(h, act["bits"], act["scale_log2"], act["signed"],
+    if params["bits"] == 1:
+        return bipolar(h, params["scale_log2"])
+    return quant(h, params["bits"], params["scale_log2"], params["signed"],
                  control=control)
+
+
+def _qweight(layer: dict, w, control):
+    """The layer's float weight through its quantizer."""
+    if layer["w_bits"] == 1:
+        return bipolar(w, layer["w_scale_log2"])
+    return quant(w, layer["w_bits"], layer["w_scale_log2"], True,
+                 narrow=True, control=control)
 
 
 def _split(a) -> tuple:
@@ -76,7 +85,7 @@ def _split(a) -> tuple:
     return head.astype(jnp.bfloat16), (a - head).astype(jnp.bfloat16)
 
 
-def _contract(fn, h, wq, control):
+def contract(fn, h, wq, control):
     """``fn(a, b)`` (an fp32-accumulating contraction) under ``control``."""
     if control == "bf16":
         return fn(h.astype(jnp.bfloat16), wq.astype(jnp.bfloat16))
@@ -91,42 +100,14 @@ def forward(layers: list[dict], weights: list, x, *, control=None):
     ``layers.float_weights`` gives them (None for weightless layers)."""
     if control not in CONTROLS:
         raise ValueError(f"unknown control {control!r}")
-    h = x.astype(jnp.float32)
-    for layer, w in zip(layers, weights):
-        op = layer["op"]
-        if op == "input_quant":
-            h = quant(h, layer["bits"], layer["scale_log2"], layer["signed"],
-                      control=control)
-        elif op in ("conv", "fc"):
-            if layer["w_bits"] == 1:
-                wq = bipolar(w, layer["w_scale_log2"])
-            else:
-                wq = quant(w, layer["w_bits"], layer["w_scale_log2"], True,
-                           narrow=True, control=control)
-            if op == "conv":
-                s, p = layer["stride"], layer["pad"]
-                fn = functools.partial(
-                    lax.conv_general_dilated, window_strides=(s, s),
-                    padding=[(p, p), (p, p)],
-                    dimension_numbers=("NCHW", "OIHW", "NCHW"),
-                    feature_group_count=layer["group"], precision=HIGHEST,
-                    preferred_element_type=jnp.float32)
-            else:
-                fn = functools.partial(jnp.dot, precision=HIGHEST,
-                                       preferred_element_type=jnp.float32)
-            h = _contract(fn, h, wq, control)
-            h = _act(h, layer["act"], control)
-        elif op == "maxpool":
-            k, s = layer["k"], layer["stride"]
-            h = lax.reduce_window(h, -jnp.inf, lax.max, (1, 1, k, k),
-                                  (1, 1, s, s), "VALID")
-        elif op == "gap":
-            h = jnp.mean(h, axis=(2, 3), keepdims=True)
-        elif op == "flatten":
-            h = h.reshape(h.shape[0], -1)
-        else:
-            raise ValueError(f"unknown layer op {op!r}")
-    return h
+
+    def step(i, layer, ins):
+        w = weights[i]
+        if w is not None:
+            w = _qweight(layer, w, control)
+        y = L.op(layer["op"]).reference(layer, ins, w, control)
+        return _act(y, layer.get("act"), control)
+    return L.walk(layers, x.astype(jnp.float32), step)[-1]
 
 
 class Reference:

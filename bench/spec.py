@@ -1,17 +1,22 @@
 """Resolve a cell of ``BENCHMARK.json`` to the files that define it.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric sits in a file of its own, found by its name under ``bench/``:
+Everything that belongs to one configuration, layer op, traffic mix or
+per-layer metric sits in a file of its own, found by its name under
+``bench/``:
 
     configs/<config>.json      sizes, bits, scales, correctness limit
     models/<family>.py         layers(cfg): the family's generic layer list
+    ops/<op>.py                one layer op: shape, MACs, graph nodes and
+                               reference (``bench/layers.py``)
     traffic/<traffic>.json     one mix; its "driver" names
     drivers/<driver>.py        the general generator that runs it
     metrics/<metric>.py        read(record) -> number, or None
     peaks.json                 peaks by device_kind
 
-So a new cell, configuration or per-layer metric is new files plus new
-entries in ``BENCHMARK.json``, and no edit of a file already there.
+A layer list may branch and merge (a layer's ``"in"`` names the earlier
+layers it reads), so a new cell, configuration, layer op or per-layer
+metric is new files plus new entries in ``BENCHMARK.json``, and no edit of
+a file already there.
 """
 from __future__ import annotations
 
@@ -41,7 +46,7 @@ def load_module(path: str, name: str):
     return mod
 
 
-def _module_name(kind: str, name: str) -> str:
+def module_name(kind: str, name: str) -> str:
     return f"bench_{kind}_" + "".join(c if c.isalnum() else "_" for c in name)
 
 
@@ -70,7 +75,7 @@ class Bench:
         """The configuration's generic layer list (``bench/layers.py``)."""
         fam = load_module(os.path.join(self.dir, "models",
                                        f"{cfg['family']}.py"),
-                          _module_name("family", cfg["family"]))
+                          module_name("family", cfg["family"]))
         return fam.layers(cfg)
 
     def traffic(self, name: str) -> dict:
@@ -79,11 +84,11 @@ class Bench:
     def driver(self, traffic_doc: dict):
         name = traffic_doc["driver"]
         return load_module(os.path.join(self.dir, "drivers", f"{name}.py"),
-                           _module_name("driver", name))
+                           module_name("driver", name))
 
     def metric_reader(self, name: str):
         return load_module(os.path.join(self.dir, "metrics", f"{name}.py"),
-                           _module_name("metric", name))
+                           module_name("metric", name))
 
     def metrics_for(self, cell: str, kind: str) -> list[dict]:
         """The ``end_to_end`` or ``per_layer`` entries the cell reports."""

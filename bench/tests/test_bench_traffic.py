@@ -22,10 +22,14 @@ def test_inputs_are_seeded_and_in_the_quantizer_range(seed):
 @pytest.mark.parametrize("name", OFFLINE)
 def test_offline_mix_fills_whole_slots(name):
     """Every call is whole slots, so the window runs one slot shape with
-    no padding, and the pool repeats the same sizes for every seed."""
+    no padding, and the pool repeats the same sizes for every seed; a mesh
+    mix's slot splits evenly over a four-chip host."""
     doc = BENCH.traffic(name)
     assert doc["driver"] == "offline"
-    assert set(doc) == {"driver", "why", "max_batch", "call_batch",
-                        "pool_calls"}
+    assert set(doc) - {"mesh"} == {"driver", "why", "max_batch",
+                                   "call_batch", "pool_calls"}
+    assert doc.get("mesh", "auto") == "auto"
+    if "mesh" in doc:
+        assert doc["max_batch"] % 4 == 0
     assert doc["call_batch"] % doc["max_batch"] == 0
     assert doc["pool_calls"] >= 2
