@@ -346,6 +346,12 @@ class CompiledPlan:
         avoided vs the old always-fp32 boundaries; ``offers`` /
         ``declined`` expose how negotiation went (a declined offer keeps
         the exact fp32 boundary the plan had before this pass).
+        ``residual_joins`` counts the ``eltwise_add`` segments by the
+        carrier of their inputs (``int4x2`` / ``int8`` / ``fp32``, the
+        wider where the two differ); ``skip_bytes_per_sample`` sums, per
+        sample, the boundary bytes of every join input produced two or
+        more segments earlier: the skip tensors a residual plan keeps
+        alive across other segments.
         """
         fp = self.fusion
         out = {"enabled": fp is not None,
@@ -361,7 +367,31 @@ class CompiledPlan:
             out["boundary_bytes_saved"] = fp.bytes_saved
             out["offers"] = fp.offered
             out["declined"] = fp.declined
+        out.update(self._residual_stats())
         return out
+
+    def _residual_stats(self) -> dict:
+        widths = ("int4x2", "int8", "fp32")
+        joins = dict.fromkeys(widths, 0)
+        skip = 0
+        producer = {t: i for i, s in enumerate(self.segments)
+                    for t in s.outputs}
+        for j, seg in enumerate(self.segments):
+            if seg.kind != "eltwise_add":
+                continue
+            kinds = []
+            for t in seg.inputs:
+                c = self.fusion.carrier(t) if self.fusion else None
+                kinds.append("fp32" if c is None else
+                             "int4x2" if c.packed else "int8")
+                if j - producer.get(t, -1) >= 2:
+                    per_sample = 1        # symbolic dims priced as 1
+                    for d in (self.graph.get_shape(t) or ())[1:]:
+                        per_sample *= 1 if d is None else int(d)
+                    skip += int(per_sample * (4.0 if c is None
+                                              else c.bytes_per_elem))
+            joins[max(kinds, key=widths.index)] += 1
+        return {"residual_joins": joins, "skip_bytes_per_sample": skip}
 
     def tuning_stats(self) -> dict:
         """Tuned-vs-default tiling telemetry aggregated over segments.
@@ -661,6 +691,14 @@ def _record_compile_metrics(plan: CompiledPlan, wall_s: float) -> None:
     reg.gauge("compile_boundary_bytes_saved",
               help="per-call boundary HBM bytes avoided vs fp32 boundaries",
               labels=model).set(fs["boundary_bytes_saved"])
+    for carrier, n in fs["residual_joins"].items():
+        reg.gauge("compile_residual_joins",
+                  help="residual eltwise_add joins, by their inputs' "
+                       "carrier", labels={**model, "carrier": carrier}).set(n)
+    reg.gauge("compile_skip_bytes_per_sample",
+              help="per-sample bytes of join inputs kept alive across "
+                   "other segments", labels=model).set(
+                       fs["skip_bytes_per_sample"])
     if plan.tune_mode != "off":
         ts = plan.tuning_stats()
         reg.counter("tune_cache_hits_total",

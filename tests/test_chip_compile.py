@@ -194,3 +194,74 @@ def test_cnv_w1a1_patches_keep_channels_on_the_lanes(one_chip):
         lanes = _lane_sizes(line)
         assert lanes and not banned[m.group(1)] & set(lanes), line.strip()
     assert seen == set(banned)
+
+
+def _plan_compiles(one_chip, name, layers, input_shape, slot):
+    """The benchmark's graph of ``layers`` compiled into a plan, its body
+    compiled for the described chip at ``slot`` rows; the plan."""
+    from bench import layers as L
+    from bench.graph import build_graph
+    from repro.core.compile import compile_graph
+    plan = compile_graph(build_graph(name, layers, L.draw_weights(layers, 3),
+                                     input_shape), interpret=False)
+    consts = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        plan.consts)
+    inputs = {plan.graph.input_names[0]: jax.ShapeDtypeStruct(
+        (slot,) + tuple(input_shape), jnp.float32, sharding=one_chip)}
+    lowered = jax.jit(plan._plan).lower(consts, inputs)
+    n_kernels = sum(1 for s in plan.segments
+                    if s.kind.startswith(("quant_conv", "quant_matmul",
+                                          "quant_dequant", "eltwise_add")))
+    assert lowered.as_text().count("tpu_custom_call") == n_kernels
+    lowered.compile()
+    return plan
+
+
+def test_residual_bottleneck_plan_compiles(one_chip):
+    """The benchmark's test bottleneck family (7x7/2 stem, padded 3x3/2
+    max-pool on packed int4 codes, 1x1 -> 3x3/2 -> 1x1 with a 1x1/2
+    projection, a join on packed carriers) at a slot of 64."""
+    from bench.tests import residual
+    cfg = residual.config()
+    plan = _plan_compiles(one_chip, cfg["name"], residual.layers(cfg),
+                          cfg["input_shape"], 64)
+    (pool,) = [s for s in plan.segments if s.kind == "quant_pool"]
+    assert pool.meta["carrier_in"] == "int4x2"
+    assert plan.fusion_stats()["residual_joins"]["int4x2"] == 1
+    assert plan.patch_layouts == {"tap_major": 2, "pointwise": 3}
+
+
+def test_resnet50_stage4_downsampling_block_compiles(one_chip):
+    """ResNet-50 w1a2's first stage-4 block at its real widths on a 14x14
+    input at the offline slot (64): the 3x3/2 conv at 512 channels
+    contracts K = 4,608 (2,304 packed int4 rows), the 1x1/2 projection
+    takes the strided pointwise slice, and the join at 7x7 (an odd width)
+    reads int8 carriers."""
+    from bench import spec
+    cfg = spec.Bench().config("resnet50-w1a2-224")
+    (cin, mid, cout, stride, scales), = [b for b in cfg["blocks"]
+                                         if b[2] == 2048 and b[3] == 2]
+    act = {"bits": cfg["act_bits"], "scale_log2": cfg["act_scale_log2"]}
+    relu = dict(act, relu=True, signed=False)
+    signed = dict(act, relu=False, signed=True)
+
+    def conv(ci, co, k, s, scale, a, **kw):
+        return dict(op="conv", cin=ci, cout=co, k=k, stride=s, pad=k // 2,
+                    group=1, w_bits=cfg["weight_bits"], w_scale_log2=scale,
+                    act=a, **kw)
+    layers = [dict(op="input_quant", bits=cfg["act_bits"], signed=False,
+                   scale_log2=cfg["act_scale_log2"]),
+              conv(cin, mid, 1, 1, scales[0], relu),
+              conv(mid, mid, 3, stride, scales[1], relu),
+              conv(mid, cout, 1, 1, scales[2], signed),
+              conv(cin, cout, 1, stride, scales[3], signed, **{"in": [0]}),
+              {"op": "add", "in": [3, 4], "act": relu}]
+    plan = _plan_compiles(one_chip, "resnet50-stage4-block", layers,
+                          (cin, 14, 14), 64)
+    assert plan.fused_counts == {"quant_dequant": 1, "quant_conv_int4": 4,
+                                 "eltwise_add": 1}
+    assert plan.fusion_stats()["residual_joins"]["int8"] == 1
+    assert plan.patch_layouts == {"tap_major": 1, "pointwise": 3}
+    assert any(v.shape == (9 * mid // 2, mid)        # packed 3x3/2 weights
+               for v in plan.consts.values())

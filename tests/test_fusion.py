@@ -381,3 +381,45 @@ def test_cnv_w1a1_zero_interpreted_pool_and_add():
     np.testing.assert_array_equal(_run(plan, x), want,
                                   err_msg=plan.describe())
     np.testing.assert_array_equal(_run(off, x), want)
+
+
+# ------------------------------------------------ residual joins (ResNet)
+
+def test_resnet50_residual_join_stats_and_gauges():
+    """The benchmark's ResNet-50 w1a2 plan at 32x32 (compiled, not run):
+    its 16 joins counted by their inputs' carrier, and the skip bytes one
+    sample keeps alive across segments, against a hand count."""
+    from bench import layers as L, spec
+    from bench.graph import build_graph
+    from repro.obs.metrics import default_registry
+    bench = spec.Bench()
+    cfg = bench.config("resnet50-w1a2-224")
+    cfg["input_shape"] = [3, 32, 32]
+    layers = bench.layers(cfg)
+    g = build_graph("resnet50-w1a2-32", layers, L.draw_weights(layers, 0),
+                    cfg["input_shape"])
+    plan = compile_graph(g)
+    fs = plan.fusion_stats()
+    # stages 1-3 end on 8x8, 4x4 and 2x2 maps (an even width: nibble-
+    # packed carriers); stage 4's 1x1 map cannot be packed and stays int8
+    assert fs["residual_joins"] == {"int4x2": 3 + 4 + 6, "int8": 3,
+                                    "fp32": 0}
+    # a projection block's join reads its branch's last conv two segments
+    # back (the projection runs in between); an identity block's join
+    # reads the previous join's output four back: one tensor per block,
+    # stage by stage (blocks x channels x H x W x bytes per code)
+    assert fs["skip_bytes_per_sample"] == (3 * 256 * 8 * 8 // 2 +
+                                           4 * 512 * 4 * 4 // 2 +
+                                           6 * 1024 * 2 * 2 // 2 +
+                                           3 * 2048 * 1 * 1)
+    reg = default_registry()
+    model = {"model": "resnet50-w1a2-32"}
+    for carrier, n in fs["residual_joins"].items():
+        assert reg.get("compile_residual_joins",
+                       dict(model, carrier=carrier)).value == n
+    assert reg.get("compile_skip_bytes_per_sample", model).value == \
+        fs["skip_bytes_per_sample"]
+
+    off = compile_graph(g, use_fusion=False)
+    assert off.fusion_stats()["residual_joins"] == {"int4x2": 0, "int8": 0,
+                                                    "fp32": 0}
